@@ -3,21 +3,15 @@ AM round-trip latency, one-sided put bandwidth, collective comparison,
 blocking vs split-phase (Extended API) comm/compute overlap, and the
 scheduler's segmented-vs-monolithic ring pipeline.
 
-Run as __main__ in a subprocess with 8 host devices (benchmarks/run.py does
-this).  Prints ``name,us_per_call,derived`` CSV rows; with ``--json PATH``
-(default ``BENCH_gas.json`` when the flag is given bare) it also writes a
+Needs 8 devices; on the CPU run it with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.  Prints
+``name,us_per_call,derived`` CSV rows; with ``--json PATH`` (default
+``BENCH_gas.json`` when the flag is given bare) it also writes a
 machine-readable artifact: every row, per-op bytes/sec, the overlap gap,
 the segmented-vs-monolithic speedups per payload tier, and the measured
 per-engine cost constants (``engine_costs``) that ``repro.core.sched``
 can load back as its planning model.
 """
-import os
-
-if __name__ == "__main__":
-    os.environ.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-    )
-
 import argparse
 import functools
 import time
@@ -26,7 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+
+from repro.launch.mesh import make_mesh
 
 from _report import make_report, new_result, write_artifact
 
@@ -66,7 +62,7 @@ def main(json_path: str | None = None) -> None:
     from repro.optim import compression
 
     N = 8
-    mesh = jax.make_mesh((N,), ("node",))
+    mesh = make_mesh((N,), ("node",))
 
     # ---- AM round trip latency vs payload -------------------------------- #
     for width in (8, 64, 512):
@@ -362,7 +358,7 @@ def main(json_path: str | None = None) -> None:
     xprobe = jnp.ones((N, 8192, 128), jnp.float32)
     for backend, (rows_a, rows_b) in probe.items():
         def hop(xl, backend=backend, rows=None):
-            eng = make_engine(backend, "node", N, interpret=True)
+            eng = make_engine(backend, "node", N)
             return eng.shift(xl[0, :rows], 1)[None]
 
         def make_f(rows):
@@ -400,4 +396,7 @@ if __name__ == "__main__":
         metavar="PATH",
         help="write the machine-readable artifact (default: BENCH_gas.json)",
     )
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main(json_path=ap.parse_args().json)

@@ -1,7 +1,8 @@
 """Compute-kernel benchmarks (single device).
 
-The Pallas kernels run in TPU-interpret mode on CPU, which measures
-*semantics*, not speed — wall numbers quantify the oracle (jnp) path and
+On the CPU the Pallas kernels run in TPU-interpret mode, which measures
+*semantics*, not speed (on a TPU they compile; rows name which ran) —
+wall numbers quantify the oracle (jnp) path and
 report kernel parity + the analytic FLOP count per call (what the roofline
 uses on target hardware).  CSV: name,us_per_call,derived.
 """
@@ -11,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops, ref
+from repro.kernels import mode, ops, ref
 
 
 def timeit(fn, *args, iters=10, warmup=2):
@@ -67,7 +68,8 @@ def main() -> None:
             # perf knobs must not change a single bit of the output
             bit = bool((got == base).all())
             print(f"paged_attention_pallas_bitinv_p{ppb}b{bb},0,{bit}")
-        print(f"paged_attention_pallas_p{ppb}b{bb},{us:.0f},interpret")
+        how = "compiled" if mode.on_tpu() else "interpret"
+        print(f"paged_attention_pallas_p{ppb}b{bb},{us:.0f},{how}")
 
     # MoE router
     T, E, K = 4096, 64, 8

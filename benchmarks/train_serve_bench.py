@@ -7,16 +7,9 @@ a machine-readable artifact: serve throughput, p50/p99 request latency,
 TTFT, and the KV-transfer goodput (bytes/sec) of the disaggregated
 cluster — the serving-side numbers CI tracks next to ``BENCH_gas``.
 
-The disaggregated section needs several host devices, so the device count
-is forced before the first JAX import (like gas_microbench).
+The disaggregated section needs several devices; on the CPU run it with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
-import os
-
-if __name__ == "__main__":
-    os.environ.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=4"
-    )
-
 import argparse
 import json
 import time
@@ -26,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from _report import append_history, make_report, new_result, write_artifact
+
+from repro.launch.mesh import make_mesh
 
 RESULT = new_result()
 report = make_report(RESULT)
@@ -560,7 +555,7 @@ def tp_sections(report) -> None:
     """
     import dataclasses
 
-    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     from repro.configs.registry import SMOKE
@@ -661,7 +656,7 @@ def tp_sections(report) -> None:
         else:
             # the REAL planned-collective shard_map program the TP
             # server decodes with, clocked on this host
-            mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+            mesh = make_mesh((tp,), ("tp",))
             sharding = NamedSharding(mesh, P("tp"))
             sparams = jax.device_put(
                 tp_lib.stack_shards(params, tp), sharding)
@@ -764,7 +759,7 @@ def overlap_bench(report) -> None:
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import gasnet
     from repro.kernels import ops
     from repro.serving import pool as pool_lib
@@ -775,7 +770,7 @@ def overlap_bench(report) -> None:
     page_elems = T * Hkv * D * 2  # K and V halves of one page
     n_batches, pages_per_batch = 8, 4
     pmap = pool_lib.PoolMap(n, pages_per_rank, page_elems)
-    mesh = jax.make_mesh((n,), ("node",))
+    mesh = make_mesh((n,), ("node",))
     ctx_gas = gasnet.Context(mesh, node_axis="node", backend="xla")
 
     rng = np.random.default_rng(0)
@@ -966,7 +961,7 @@ def obs_sections(report) -> None:
     # idempotent, so re-running it never perturbs served state).
     prof = DeviceProfiler()
     kernel_best = prof.profile_many(
-        kernel_ops.profiling_targets(interpret=True), rounds=4, warmup=1)
+        kernel_ops.profiling_targets(), rounds=4, warmup=1)
     for kname in sorted(kernel_best):
         rec = next(r for r in prof.records if r["name"] == kname)
         report(f"obs_profile_{kname}", kernel_best[kname],
@@ -995,7 +990,7 @@ def obs_sections(report) -> None:
         print("obs cost-model rows skipped: needs >= 2 host devices")
         return
     n = 2
-    mesh = jax.make_mesh((n,), ("node",))
+    mesh = make_mesh((n,), ("node",))
     gctx = gasnet.Context(mesh, node_axis="node", backend="xla")
     tr = obs_trace.enable(capacity=4096)
     sizes = (1 << 16, 1 << 18, 1 << 20)  # 64 KiB, 256 KiB, 1 MiB
@@ -1091,4 +1086,7 @@ if __name__ == "__main__":
         unknown = [s for s in picked if s not in SECTIONS]
         if unknown:
             ap.error(f"unknown sections {unknown}; choose from {SECTIONS}")
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main(json_path=args.json, sections=picked)

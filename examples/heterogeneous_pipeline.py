@@ -23,9 +23,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import gasnet
+from repro.launch.mesh import make_mesh
 
 N = 4
-mesh = jax.make_mesh((N,), ("node",))
+mesh = make_mesh((N,), ("node",))
 
 
 # A 3-stage pipeline over the ring: each node transforms what the previous
@@ -43,8 +44,7 @@ def pipeline_program(node, inbox, x):
 
 
 def run(backend: str) -> np.ndarray:
-    ctx = gasnet.Context(mesh, node_axis="node", backend=backend,
-                         interpret=True)
+    ctx = gasnet.Context(mesh, node_axis="node", backend=backend)
     aspace = ctx.address_space()
     aspace.register("inbox", (128,), jnp.float32)
     inbox = aspace.alloc("inbox")
@@ -83,7 +83,7 @@ print("Act 3: disaggregated serving — prefill node puts a KV cache into the")
 print("decode node's memory with ONE one-sided GAScore transfer")
 
 from repro.kernels import gascore
-from repro.compat import shard_map
+from jax import shard_map
 
 S, KH, Dh = 32, 2, 16
 kv = jnp.asarray(

@@ -18,9 +18,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives, gasnet
+from repro.launch.mesh import make_mesh
 
 N = 8
-mesh = jax.make_mesh((N,), ("node",))
+mesh = make_mesh((N,), ("node",))
 ctx = gasnet.Context(mesh, node_axis="node", backend="xla",
                      am_payload_width=4)
 

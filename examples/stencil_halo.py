@@ -20,13 +20,14 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import gasnet
+from repro.launch.mesh import make_mesh
 
 N_NODES = 8
 LOCAL = 64  # interior cells per node
 STEPS = 400
 ALPHA = 0.25
 
-mesh = jax.make_mesh((N_NODES,), ("node",))
+mesh = make_mesh((N_NODES,), ("node",))
 ctx = gasnet.Context(mesh, node_axis="node", backend="xla")
 
 # segment layout per node: [ghost_left | interior(LOCAL) | ghost_right]

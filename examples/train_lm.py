@@ -24,19 +24,20 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import ckpt
 from repro.configs.registry import SMOKE
 from repro.core import sched
+from repro.launch.mesh import make_mesh
 from repro.core.engine import make_engine
 from repro.data.synthetic import SyntheticLM
 from repro.models.build import build_model
 from repro.optim import adamw, compression
 from repro.parallel.ctx import RunCtx
 from repro.runtime.ft import elastic_plan
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def make_step(model, opt_cfg, mesh, n_nodes, reduce_mode):
@@ -122,7 +123,7 @@ def main() -> None:
     os.makedirs(args.ckpt_dir, exist_ok=True)
 
     def build(n_nodes):
-        mesh = Mesh(np.array(jax.devices()[:n_nodes]), ("node",))
+        mesh = make_mesh((n_nodes,), ("node",))
         step = make_step(model, opt_cfg, mesh, n_nodes, args.reduce)
         return mesh, step
 

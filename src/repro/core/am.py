@@ -375,27 +375,16 @@ def route(
             return all_to_all_fn(x)
         return lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
 
-    def exchange(x: jax.Array) -> jax.Array:
-        # (n_nodes*K, ...) -> regroup so dim0 blocks are per-destination
-        return a2a(x)
-
+    # dim0 blocks are per-destination; ``dest`` does not travel: on
+    # arrival it is rewritten to the source node of each slot
+    valid = a2a(packed.valid)
     recv = AMBatch(
-        dest=exchange(packed.dest),
-        handler=exchange(packed.handler),
-        args=exchange(packed.args),
-        payload=exchange(packed.payload),
-        valid=exchange(packed.valid),
-        count=jnp.zeros((), jnp.int32),
-    )
-    # annotate source node per received slot
-    src = jnp.repeat(jnp.arange(n_nodes, dtype=jnp.int32), K)
-    recv = AMBatch(
-        dest=src,  # now: sender token
-        handler=recv.handler,
-        args=recv.args,
-        payload=recv.payload,
-        valid=recv.valid,
-        count=jnp.sum(recv.valid).astype(jnp.int32),
+        dest=jnp.repeat(jnp.arange(n_nodes, dtype=jnp.int32), K),
+        handler=a2a(packed.handler),
+        args=a2a(packed.args),
+        payload=a2a(packed.payload),
+        valid=valid,
+        count=jnp.sum(valid).astype(jnp.int32),
     )
     return recv, dropped
 

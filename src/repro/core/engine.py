@@ -324,16 +324,11 @@ class XlaEngine(CommEngine):
 class GascoreEngine(CommEngine):
     """Hardware GASNet node: Pallas remote-DMA (GAScore) as the transport.
 
-    ``interpret=True`` runs the kernels in TPU-interpret mode (CPU
-    emulation of DMAs + semaphores); on real TPUs pass ``interpret=False``
-    to compile Mosaic kernels over ICI.
+    The kernels compile to Mosaic over ICI on a TPU and run in
+    TPU-interpret mode (CPU emulation of DMAs + semaphores) on the CPU.
     """
 
     name = "gascore"
-
-    def __init__(self, axis: str, n_nodes: int, interpret: bool = True):
-        super().__init__(axis, n_nodes)
-        self.interpret = interpret
 
     # kernels are imported lazily to keep `repro.core` import-light and to
     # avoid a core <-> kernels import cycle.
@@ -346,23 +341,23 @@ class GascoreEngine(CommEngine):
         if k % self.n_nodes == 0:
             return x
         return self._k().ring_shift(
-            x, k=k, axis=self.axis, n_nodes=self.n_nodes, interpret=self.interpret
+            x, k=k, axis=self.axis, n_nodes=self.n_nodes
         )
 
     def permute(self, x: jax.Array, dst: Sequence[int]) -> jax.Array:
         return self._k().perm_put(
             x, dst=tuple(int(d) for d in dst), axis=self.axis,
-            n_nodes=self.n_nodes, interpret=self.interpret,
+            n_nodes=self.n_nodes,
         )
 
     def all_gather(self, x: jax.Array) -> jax.Array:
         return self._k().ring_all_gather(
-            x, axis=self.axis, n_nodes=self.n_nodes, interpret=self.interpret
+            x, axis=self.axis, n_nodes=self.n_nodes
         )
 
     def reduce_scatter(self, x: jax.Array) -> jax.Array:
         return self._k().ring_reduce_scatter(
-            x, axis=self.axis, n_nodes=self.n_nodes, interpret=self.interpret
+            x, axis=self.axis, n_nodes=self.n_nodes
         )
 
     def all_reduce(self, x: jax.Array) -> jax.Array:
@@ -407,7 +402,6 @@ class EngineMap(CommEngine):
         self,
         axis: str,
         backends: Sequence[str],
-        interpret: bool = True,
         engines: dict | None = None,
     ):
         super().__init__(axis, len(backends))
@@ -418,7 +412,7 @@ class EngineMap(CommEngine):
                 uniq.append(b)
         if engines is None:
             engines = {
-                b: _make_single_engine(b, axis, self.n_nodes, interpret)
+                b: _make_single_engine(b, axis, self.n_nodes)
                 for b in uniq
             }
         self._engines = engines
@@ -431,6 +425,8 @@ class EngineMap(CommEngine):
         self.can_permute_partial = all(
             self._engines[b].can_permute_partial for b in self._uniq
         )
+        # (trace, output) of the map's last transport (see _each)
+        self._last = None
 
     def backend_of(self, rank: int) -> str:
         return self.backends[rank % self.n_nodes]
@@ -443,6 +439,38 @@ class EngineMap(CommEngine):
         return len(self._uniq) > 1
 
     # -- per-edge transport selection ----------------------------------- #
+    def _each(self, move, x: jax.Array) -> dict:
+        """``move(engine, x)`` on every member engine.
+
+        A GAScore kernel blocks until every peer has entered it, and an
+        XLA collective until every peer has joined it.  On a TPU every
+        device runs the program's operations in one order.  The CPU
+        runtime (interpret mode) runs independent operations concurrently,
+        so one device can sit in an XLA collective while its peers sit in
+        a GAScore kernel, and all wait forever.  There, each transport's
+        input is made to depend on the output of the map's previous
+        transport in the same trace, across calls too: the segmented ring
+        issues independent shifts back to back.  The dependence is a
+        select on ``me >= n``, which never holds but which the compiler
+        cannot prove; the CPU compiler drops an ``optimization_barrier``
+        and the dependence with it.  The map keeps that output until its
+        next transport; a transport in another trace (a loop body, a later
+        jit) starts a new chain."""
+        from repro.kernels import mode
+
+        if mode.on_tpu():
+            return {b: move(self._engines[b], x) for b in self._uniq}
+        state = jax.core.get_opaque_trace_state()
+        prev = self._last[1] if self._last and self._last[0] == state else None
+        outs = {}
+        for b in self._uniq:
+            if prev is not None:
+                after = jnp.ravel(prev)[0].astype(x.dtype)
+                x = jnp.where(self.my_id() >= self.n_nodes, after, x)
+            outs[b] = prev = move(self._engines[b], x)
+        self._last = (state, prev)
+        return outs
+
     def _select_by_src(self, outs: dict, src: jax.Array) -> jax.Array:
         """Each receiver keeps the copy carried by its *sender's* engine."""
         acc = outs[self._uniq[0]]
@@ -455,7 +483,7 @@ class EngineMap(CommEngine):
             return x
         if not self.is_heterogeneous:
             return self._engines[self._uniq[0]].shift(x, k)
-        outs = {b: self._engines[b].shift(x, k) for b in self._uniq}
+        outs = self._each(lambda e, v: e.shift(v, k), x)
         src = lax.rem(self.my_id() - k + 2 * self.n_nodes, self.n_nodes)
         return self._select_by_src(outs, src)
 
@@ -468,7 +496,7 @@ class EngineMap(CommEngine):
                 "partial permute (None destinations) unsupported by "
                 f"engine map {self.backends}"
             )
-        outs = {b: self._engines[b].permute(x, dst) for b in self._uniq}
+        outs = self._each(lambda e, v: e.permute(v, dst), x)
         # receiver j's sender is inv[j]; non-destinations receive zeros
         # from every member engine, so any branch is correct for them.
         inv = [0] * self.n_nodes
@@ -497,13 +525,11 @@ class EngineMap(CommEngine):
     # all_to_all: inherited split-phase exchange over shift_nb.
 
 
-def _make_single_engine(
-    backend: str, axis: str, n_nodes: int, interpret: bool
-) -> CommEngine:
+def _make_single_engine(backend: str, axis: str, n_nodes: int) -> CommEngine:
     if backend == "xla":
         return XlaEngine(axis, n_nodes)
     if backend == "gascore":
-        return GascoreEngine(axis, n_nodes, interpret=interpret)
+        return GascoreEngine(axis, n_nodes)
     raise ValueError(f"unknown engine backend {backend!r}")
 
 
@@ -528,9 +554,7 @@ def parse_backend_spec(backend, n_nodes: int) -> Tuple[str, ...]:
     return tuple(names[i % len(names)] for i in range(n_nodes))
 
 
-def make_engine(
-    backend, axis: str, n_nodes: int, interpret: bool = True
-) -> CommEngine:
+def make_engine(backend, axis: str, n_nodes: int) -> CommEngine:
     """Build the engine (or heterogeneous :class:`EngineMap`) for a mesh axis.
 
     ``backend`` is a single engine name, a comma-separated per-rank pattern,
@@ -540,5 +564,5 @@ def make_engine(
     ranks = parse_backend_spec(backend, n_nodes)
     uniq = set(ranks)
     if len(uniq) == 1:
-        return _make_single_engine(ranks[0], axis, n_nodes, interpret)
-    return EngineMap(axis, ranks, interpret=interpret)
+        return _make_single_engine(ranks[0], axis, n_nodes)
+    return EngineMap(axis, ranks)

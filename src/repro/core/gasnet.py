@@ -73,7 +73,6 @@ from repro.core import am as am_lib
 from repro.core import extended
 from repro.core.addrspace import AddressSpace
 from repro.core.engine import CommEngine, make_engine
-from repro.compat import shard_map
 from repro.obs import trace as obs_trace
 
 __all__ = ["Shift", "Perm", "Context", "Node"]
@@ -672,7 +671,6 @@ class Context:
         mesh: jax.sharding.Mesh,
         node_axis: str = "node",
         backend: str = "xla",
-        interpret: bool = True,
         am_capacity: int = 16,
         am_payload_width: int = 8,
         am_per_peer_capacity: int | None = None,
@@ -680,7 +678,6 @@ class Context:
         self.mesh = mesh
         self.node_axis = node_axis
         self.backend = backend
-        self.interpret = interpret
         self.handlers = am_lib.HandlerTable()
         self.am_capacity = am_capacity
         self.am_payload_width = am_payload_width
@@ -695,9 +692,7 @@ class Context:
         return self.handlers.register(name, fn)
 
     def make_engine(self) -> CommEngine:
-        return make_engine(
-            self.backend, self.node_axis, self.n_nodes, interpret=self.interpret
-        )
+        return make_engine(self.backend, self.node_axis, self.n_nodes)
 
     def make_node(self) -> Node:
         return Node(
@@ -733,7 +728,7 @@ class Context:
             node = self.make_node()
             return program(node, *local_args)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=in_specs,

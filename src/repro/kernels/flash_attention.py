@@ -12,8 +12,8 @@ Supports causal masking, GQA (q heads grouped over fewer KV heads, resolved
 in the K/V index_map so KV tiles are fetched once per group), and a sliding
 local-attention window (gemma3 / recurrentgemma local layers).
 
-Oracle: ``repro.kernels.ref.attention``.  Validated under interpret mode;
-on real TPUs pass ``interpret=False``.
+Oracle: ``repro.kernels.ref.attention``.  Compiled on a TPU, interpreted
+on the CPU (``repro.kernels.mode``).
 """
 from __future__ import annotations
 
@@ -26,7 +26,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 __all__ = ["flash_attention"]
 
@@ -110,7 +110,6 @@ def _fa_kernel(
         "scale",
         "block_q",
         "block_k",
-        "interpret",
         "return_lse",
     ),
 )
@@ -124,7 +123,6 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
     return_lse: bool = False,
 ) -> jax.Array:
     """Blockwise attention.
@@ -193,10 +191,10 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="flash_attention_fwd",
     )(q, k, v)
     if return_lse:

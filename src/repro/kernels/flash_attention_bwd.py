@@ -32,7 +32,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 from repro.kernels.flash_attention import flash_attention
 
@@ -185,7 +185,7 @@ def _dq_kernel(
 # --------------------------------------------------------------------------- #
 @functools.partial(
     jax.custom_vjp,
-    nondiff_argnums=(3, 4, 5, 6, 7, 8),
+    nondiff_argnums=(3, 4, 5, 6, 7),
 )
 def flash_attention_vjp(
     q, k, v,
@@ -194,22 +194,21 @@ def flash_attention_vjp(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
 ):
-    out, _ = _fwd(q, k, v, causal, window, scale, block_q, block_k, interpret)
+    out, _ = _fwd(q, k, v, causal, window, scale, block_q, block_k)
     return out
 
 
-def _fwd(q, k, v, causal, window, scale, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, window, scale, block_q, block_k):
     out, lse = flash_attention(
         q, k, v, causal=causal, window=window, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        block_q=block_q, block_k=block_k,
         return_lse=True,
     )
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, window, scale, block_q, block_k, interpret, res, dout):
+def _bwd(causal, window, scale, block_q, block_k, res, dout):
     q, k, v, out, lse = res
     B, Hq, Sq, D = q.shape
     _, KH, Sk, _ = k.shape
@@ -256,10 +255,10 @@ def _bwd(causal, window, scale, block_q, block_k, interpret, res, dout):
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="flash_attention_dkv",
     )(qg, k, v, dog, lseg, deltag)
     dk, dv = dkv
@@ -284,10 +283,10 @@ def _bwd(causal, window, scale, block_q, block_k, interpret, res, dout):
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="flash_attention_dq",
     )(q, kx, vx, dout, lse, delta)
 

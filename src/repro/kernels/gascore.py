@@ -20,11 +20,17 @@ Kernels:
                                 kernel; compute proceeds between start/wait).
 - :func:`ring_reduce_scatter` — n-1 chained put+accumulate hops.
 
-All kernels run under TPU interpret mode on CPU (``interpret=True``, the
-validation path in this repo) and compile to Mosaic for real ICI
-(``interpret=False``).  They must be invoked inside a ``shard_map`` over
-``axis``; the node axis must be the kernel's only mesh axis (1-D subgrid),
-which is how the GAS layer always invokes them.
+All kernels compile to Mosaic for real ICI on a TPU and run in TPU
+interpret mode on the CPU (``repro.kernels.mode``).  They must be invoked
+inside a ``shard_map`` over ``axis``; the node axis must be the kernel's
+only mesh axis (1-D subgrid), which is how the GAS layer always invokes
+them.
+
+Every kernel opens with a barrier on the global barrier semaphore
+(``collective_id`` set): a remote DMA writes into a buffer of the peer's
+instance of the same kernel, so no node may write before every node has
+entered it.  Without the handshake a fast node's put can land in a peer
+buffer that an earlier program on that peer still owns.
 
 VMEM/alignment notes (target hardware): chunks are staged through VMEM
 scratch; callers should keep the trailing dim a multiple of 128 and the
@@ -42,7 +48,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 __all__ = [
     "ring_shift",
@@ -53,26 +59,59 @@ __all__ = [
 ]
 
 
-def _interp(interpret: bool):
-    return compat.tpu_interpret(interpret)
+def _params() -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(collective_id=0)
+
+
+def _enter(axis: str, n_nodes: int) -> None:
+    """Barrier at kernel entry: signal every peer, wait for all of them
+    (see the module docstring)."""
+    sem = pltpu.get_barrier_semaphore()
+    me = lax.axis_index(axis)
+    for d in range(1, n_nodes):
+        pltpu.semaphore_signal(
+            sem, 1, device_id=(lax.rem(me + d, n_nodes),),
+            device_id_type=pltpu.DeviceIdType.MESH,
+        )
+    pltpu.semaphore_wait(sem, n_nodes - 1)
 
 
 def _any_spec() -> pl.BlockSpec:
     return pl.BlockSpec(memory_space=pl.ANY)
 
 
+def _hbm(x: jax.Array) -> jax.Array:
+    """``x`` placed in HBM for a kernel operand (see :func:`_hbm_out`)."""
+    return pltpu.with_memory_space_constraint(x, pltpu.HBM)
+
+
+def _hbm_out(shape, dtype):
+    """A kernel result placed in HBM.  A remote DMA writes the peer's
+    buffer at the address it has in the peer's instance of the kernel;
+    left to XLA, small buffers go to VMEM, where a put from a peer does
+    not arrive (the AM flags came back all zero on four v5e chips)."""
+    return pltpu.HBM(shape, dtype)
+
+
 # --------------------------------------------------------------------------- #
 # point-to-point puts
 # --------------------------------------------------------------------------- #
 def ring_shift(
-    x: jax.Array, *, k: int, axis: str, n_nodes: int, interpret: bool = True
+    x: jax.Array, *, k: int, axis: str, n_nodes: int
 ) -> jax.Array:
     """Every node's ``x`` lands on node ``(me + k) % n`` (one remote DMA)."""
     k = k % n_nodes
     if k == 0:
         return x
+    if x.ndim == 0:  # Mosaic blocks have rank >= 1
+        return ring_shift(x[None], k=k, axis=axis, n_nodes=n_nodes)[0]
+    if x.dtype == jnp.bool_:  # Mosaic DMAs do not move bools
+        return ring_shift(
+            x.astype(jnp.int32), k=k, axis=axis, n_nodes=n_nodes
+        ) != 0
 
     def kernel(x_ref, o_ref, send_sem, recv_sem):
+        _enter(axis, n_nodes)
         me = lax.axis_index(axis)
         dst = lax.rem(me + k, n_nodes)
         rdma = pltpu.make_async_remote_copy(
@@ -80,7 +119,7 @@ def ring_shift(
             dst_ref=o_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=compat.dma_device_id(dst),
+            device_id=(dst,),
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
@@ -88,13 +127,14 @@ def ring_shift(
 
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=_hbm_out(x.shape, x.dtype),
         in_specs=[_any_spec()],
         out_specs=_any_spec(),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        interpret=_interp(interpret),
+        interpret=mode.interpret(),
+        compiler_params=_params(),
         name=f"gascore_ring_shift_{k}",
-    )(x)
+    )(_hbm(x))
 
 
 def perm_put(
@@ -103,7 +143,6 @@ def perm_put(
     dst: Tuple[int, ...],
     axis: str,
     n_nodes: int,
-    interpret: bool = True,
 ) -> jax.Array:
     """Put along a static permutation: node i's ``x`` lands on ``dst[i]``.
 
@@ -114,8 +153,15 @@ def perm_put(
     """
     if sorted(dst) != list(range(n_nodes)):
         raise ValueError(f"perm_put requires a bijection, got {dst}")
+    if x.ndim == 0:  # Mosaic blocks have rank >= 1
+        return perm_put(x[None], dst=dst, axis=axis, n_nodes=n_nodes)[0]
+    if x.dtype == jnp.bool_:  # Mosaic DMAs do not move bools
+        return perm_put(
+            x.astype(jnp.int32), dst=dst, axis=axis, n_nodes=n_nodes
+        ) != 0
 
     def kernel(dst_ref, x_ref, o_ref, send_sem, recv_sem):
+        _enter(axis, n_nodes)
         me = lax.axis_index(axis)
         target = dst_ref[me]
         rdma = pltpu.make_async_remote_copy(
@@ -123,7 +169,7 @@ def perm_put(
             dst_ref=o_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=compat.dma_device_id(target),
+            device_id=(target,),
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
@@ -132,16 +178,17 @@ def perm_put(
     dst_arr = jnp.asarray(dst, jnp.int32)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=_hbm_out(x.shape, x.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             _any_spec(),
         ],
         out_specs=_any_spec(),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        interpret=_interp(interpret),
+        interpret=mode.interpret(),
+        compiler_params=_params(),
         name="gascore_perm_put",
-    )(dst_arr, x)
+    )(dst_arr, _hbm(x))
 
 
 def offset_put(
@@ -152,7 +199,6 @@ def offset_put(
     k: int,
     axis: str,
     n_nodes: int,
-    interpret: bool = True,
 ) -> jax.Array:
     """AMLong via GAScore: write ``data`` into the partition of node
     ``(me + k) % n`` of segment ``seg`` at *sender-chosen* element offset
@@ -171,6 +217,7 @@ def offset_put(
 
     def kernel(off_ref, data_ref, seg_in_ref, seg_ref, send_sem, recv_sem):
         del seg_in_ref  # aliased with seg_ref; content already in place
+        _enter(axis, n_nodes)
         me = lax.axis_index(axis)
         dst = lax.rem(me + k, n_nodes)
         rdma = pltpu.make_async_remote_copy(
@@ -178,7 +225,7 @@ def offset_put(
             dst_ref=seg_ref.at[pl.ds(off_ref[0], L)],
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=compat.dma_device_id(dst),
+            device_id=(dst,),
             device_id_type=pltpu.DeviceIdType.MESH,
         )
         rdma.start()
@@ -196,16 +243,17 @@ def offset_put(
         out_specs=_any_spec(),
         input_output_aliases={2: 0},
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        interpret=_interp(interpret),
+        interpret=mode.interpret(),
+        compiler_params=_params(),
         name=f"gascore_offset_put_{k}",
-    )(off_arr, data, seg)
+    )(off_arr, _hbm(data), seg)  # constraining seg breaks the alias
 
 
 # --------------------------------------------------------------------------- #
 # fused ring collectives
 # --------------------------------------------------------------------------- #
 def ring_all_gather(
-    x: jax.Array, *, axis: str, n_nodes: int, interpret: bool = True
+    x: jax.Array, *, axis: str, n_nodes: int
 ) -> jax.Array:
     """All-gather: local chunk (m, ...) -> tiled (n*m, ...).
 
@@ -219,6 +267,7 @@ def ring_all_gather(
     chunk_shape = x.shape
 
     def kernel(x_ref, o_ref, local_sem, send_sems, recv_sems):
+        _enter(axis, n_nodes)
         me = lax.axis_index(axis)
         right = lax.rem(me + 1, n)
         # publish my own chunk into my slot (local DMA)
@@ -237,7 +286,7 @@ def ring_all_gather(
                 dst_ref=o_ref.at[slot],
                 send_sem=send_sems.at[h],
                 recv_sem=recv_sems.at[h],
-                device_id=compat.dma_device_id(right),
+                device_id=(right,),
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             rdma.start()
@@ -248,7 +297,7 @@ def ring_all_gather(
 
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((n,) + chunk_shape, x.dtype),
+        out_shape=_hbm_out((n,) + chunk_shape, x.dtype),
         in_specs=[_any_spec()],
         out_specs=_any_spec(),
         scratch_shapes=[
@@ -256,14 +305,15 @@ def ring_all_gather(
             pltpu.SemaphoreType.DMA((n - 1,)),
             pltpu.SemaphoreType.DMA((n - 1,)),
         ],
-        interpret=_interp(interpret),
+        interpret=mode.interpret(),
+        compiler_params=_params(),
         name="gascore_ring_all_gather",
-    )(x)
+    )(_hbm(x))
     return out.reshape((n * chunk_shape[0],) + chunk_shape[1:])
 
 
 def ring_reduce_scatter(
-    x: jax.Array, *, axis: str, n_nodes: int, interpret: bool = True
+    x: jax.Array, *, axis: str, n_nodes: int
 ) -> jax.Array:
     """Reduce-scatter: (n*m, ...) -> summed local chunk (m, ...).
 
@@ -282,6 +332,7 @@ def ring_reduce_scatter(
     xb = x.reshape((n,) + chunk_shape)
 
     def kernel(x_ref, o_ref, acc, recv2, mine, csem, send_sems, recv_sems):
+        _enter(axis, n_nodes)
         me = lax.axis_index(axis)
         right = lax.rem(me + 1, n)
         # seed: my contribution to chunk (me - 1) mod n
@@ -304,7 +355,7 @@ def ring_reduce_scatter(
                 dst_ref=recv2.at[slot],
                 send_sem=send_sems.at[h - 1],
                 recv_sem=recv_sems.at[h - 1],
-                device_id=compat.dma_device_id(right),
+                device_id=(right,),
                 device_id_type=pltpu.DeviceIdType.MESH,
             )
             rdma.start()
@@ -324,7 +375,7 @@ def ring_reduce_scatter(
 
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(chunk_shape, x.dtype),
+        out_shape=_hbm_out(chunk_shape, x.dtype),
         in_specs=[_any_spec()],
         out_specs=_any_spec(),
         scratch_shapes=[
@@ -335,7 +386,8 @@ def ring_reduce_scatter(
             pltpu.SemaphoreType.DMA((n - 1,)),
             pltpu.SemaphoreType.DMA((n - 1,)),
         ],
-        interpret=_interp(interpret),
+        interpret=mode.interpret(),
+        compiler_params=_params(),
         name="gascore_ring_reduce_scatter",
-    )(xb)
+    )(_hbm(xb))
     return out

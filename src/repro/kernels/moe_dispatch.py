@@ -31,7 +31,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 __all__ = ["moe_router"]
 
@@ -98,7 +98,7 @@ def _router_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "capacity", "renormalize", "block_t", "interpret"),
+    static_argnames=("k", "capacity", "renormalize", "block_t"),
 )
 def moe_router(
     logits: jax.Array,
@@ -107,7 +107,6 @@ def moe_router(
     capacity: int,
     renormalize: bool = True,
     block_t: int = 256,
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Route tokens to experts with capacity-bounded slot assignment.
 
@@ -148,9 +147,9 @@ def moe_router(
         out_specs=tuple(pl.BlockSpec((block_t, k), blk) for _ in range(4)),
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM((1, E), jnp.int32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="moe_router",
     )(logits)

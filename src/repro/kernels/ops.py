@@ -5,8 +5,8 @@ Every op takes ``impl`` ∈ {"pallas", "ref"}: "ref" is the pure-jnp oracle
 node*).  Models call these wrappers, so migrating a hot spot between the
 two is a config flag — the paper's development story.
 
-On CPU the pallas path runs under TPU-interpret mode automatically; pass
-``interpret=False`` on real TPUs.
+The pallas path compiles on a TPU and runs in TPU-interpret mode on the
+CPU; ``repro.kernels.mode`` decides which.
 """
 from __future__ import annotations
 
@@ -51,7 +51,6 @@ def attention(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     impl: str = "ref",
-    interpret: bool = True,
     block_q: int = 128,
     block_k: int = 128,
 ) -> jax.Array:
@@ -59,12 +58,12 @@ def attention(
         # differentiable: custom-VJP pairing the fwd kernel with the
         # blockwise dQ/dKV backward kernels (flash_attention_bwd.py)
         return _fab.flash_attention_vjp(
-            q, k, v, causal, window, scale, block_q, block_k, interpret
+            q, k, v, causal, window, scale, block_q, block_k
         )
     if impl == "pallas_fwd":
         return _fa.flash_attention(
             q, k, v, causal=causal, window=window, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=interpret,
+            block_q=block_q, block_k=block_k,
         )
     return ref.attention(q, k, v, causal=causal, window=window, scale=scale)
 
@@ -78,7 +77,6 @@ def paged_attention(
     *,
     scale: Optional[float] = None,
     impl: str = "ref",
-    interpret: bool = True,
     pages_per_block: Optional[int] = None,
     block_b: Optional[int] = None,
 ) -> jax.Array:
@@ -93,7 +91,7 @@ def paged_attention(
     if impl == "pallas":
         return _pa.paged_attention(
             q, k_pages, v_pages, page_table, lengths,
-            scale=scale, interpret=interpret,
+            scale=scale,
             pages_per_block=pages_per_block, block_b=block_b,
         )
     return ref.paged_attention(
@@ -108,13 +106,12 @@ def moe_router(
     capacity: int,
     renormalize: bool = True,
     impl: str = "ref",
-    interpret: bool = True,
     block_t: int = 256,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     if impl == "pallas":
         return _moe.moe_router(
             logits, k=k, capacity=capacity, renormalize=renormalize,
-            block_t=block_t, interpret=interpret,
+            block_t=block_t,
         )
     return ref.route_topk(logits, k=k, capacity=capacity, renormalize=renormalize)
 
@@ -126,13 +123,12 @@ moe_combine = ref.moe_combine
 
 
 def selective_scan(
-    x, dt, a, b, c, d, *, impl: str = "ref", interpret: bool = True,
+    x, dt, a, b, c, d, *, impl: str = "ref",
     block_d: int = 512, block_s: int = 128,
 ) -> jax.Array:
     if impl == "pallas":
         return _ssm.selective_scan(
             x, dt, a, b, c, d, block_d=block_d, block_s=block_s,
-            interpret=interpret,
         )
     if impl == "chunked":
         return ref.selective_scan_chunked(x, dt, a, b, c, d, chunk=block_s)
@@ -140,12 +136,12 @@ def selective_scan(
 
 
 def gated_linear_scan(
-    a, b, *, impl: str = "ref", interpret: bool = True,
+    a, b, *, impl: str = "ref",
     block_d: int = 512, block_s: int = 128,
 ) -> jax.Array:
     if impl == "pallas":
         return _rglru.gated_linear_scan(
-            a, b, block_d=block_d, block_s=block_s, interpret=interpret
+            a, b, block_d=block_d, block_s=block_s
         )
     if impl == "chunked":
         return ref.gated_linear_scan_chunked(a, b, chunk=block_s * 2)
@@ -160,7 +156,6 @@ def profiling_targets(
     head_dim: int = 64,
     n_pages: int = 16,
     page_tokens: int = 8,
-    interpret: bool = True,
     seed: int = 0,
 ):
     """Named, jitted paged-attention closures over one synthetic decode
@@ -192,7 +187,7 @@ def profiling_targets(
         def run():
             return paged_attention(
                 q, k_pages, v_pages, table, lengths,
-                impl=impl, interpret=interpret,
+                impl=impl,
             )
         return run
 

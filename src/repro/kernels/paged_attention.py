@@ -10,20 +10,28 @@ Serving-grade blocking (the SMI lesson: decouple message granularity
 from transfer granularity — here, page granularity from kernel-grid
 granularity):
 
-- **Batch blocking** — the grid is ``(B/BLOCK_B, Hkv, NP/PAGES_PER_BLOCK)``,
+- **Batch blocking** — the grid is ``(B/BLOCK_B, NP/PAGES_PER_BLOCK)``,
   so one kernel program serves ``BLOCK_B`` requests at once and their
   page DMAs are issued as one burst per block instead of one grid step
   per (request, page).
 - **Page-block streaming** — K/V stay in HBM (``memory_space=ANY``); the
   kernel resolves physical pages through the scalar-prefetched table and
-  copies ``BLOCK_B x PAGES_PER_BLOCK`` pages per grid step into VMEM
-  scratch with explicit ``make_async_copy`` DMAs — the hardware analogue
-  of the pool's one-sided vectored ``get_nbv`` page fetch, one level down
-  the memory hierarchy.
+  copies ``BLOCK_B x PAGES_PER_BLOCK`` whole pages (every KV head) per
+  grid step into VMEM scratch with explicit ``make_async_copy`` DMAs —
+  the hardware analogue of the pool's one-sided vectored ``get_nbv`` page
+  fetch, one level down the memory hierarchy.  A page is copied whole
+  because HBM tiles its ``(Hkv, D)`` dims: one head of a bf16 page is not
+  a tile-aligned slice.
 - **Double buffering** — two VMEM slots: the next page block's DMA burst
   is issued *before* the current block's compute, so the wire hides
   behind the online-softmax work exactly like the split-phase GASNet
   ops hide behind the decode step.
+
+All heads of a request are scored in one matmul: the page is viewed as
+``(T * Hkv, D)`` rows, every query head is scored against every row, and
+rows of other KV heads are masked like positions past the length.  The
+masked products are exact zeros, so each head's softmax and value sum
+are over its own rows only.
 
 Blocking is a pure perf knob, never a semantics knob: the per-request
 online-softmax update is computed page by page in logical order with
@@ -35,8 +43,8 @@ Positions past ``lengths[b]`` are masked *before* the running max and V
 is zeroed at masked positions, so padded page-table entries may point at
 any physical page — even one holding NaN garbage.
 
-Oracle: ``repro.kernels.ref.paged_attention``.  Validated under interpret
-mode; on real TPUs pass ``interpret=False``.
+Oracle: ``repro.kernels.ref.paged_attention``.  Compiled on a TPU,
+interpreted on the CPU (``repro.kernels.mode``).
 """
 from __future__ import annotations
 
@@ -49,7 +57,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 __all__ = ["paged_attention", "DEFAULT_PAGES_PER_BLOCK", "DEFAULT_BLOCK_B"]
 
@@ -65,28 +73,29 @@ DEFAULT_PAGES_PER_BLOCK = 4
 def _pa_kernel(
     table_ref,  # scalar prefetch: (B * NP,) physical page ids
     len_ref,  # scalar prefetch: (B,) live lengths
-    q_ref,  # (BLOCK_B, group, D)
+    q_ref,  # (BLOCK_B, Hq, D)
     k_hbm,  # (P, T, Hkv, D) — full pool, memory_space=ANY
     v_hbm,  # (P, T, Hkv, D)
-    o_ref,  # (BLOCK_B, group, D)
-    k_buf,  # VMEM (2, BLOCK_B, PPB, T, D) double-buffered page blocks
+    o_ref,  # (BLOCK_B, Hq, D)
+    k_buf,  # VMEM (2, BLOCK_B, PPB, T, Hkv, D) double-buffered page blocks
     v_buf,
     sems,  # DMA semaphores (2, 2, BLOCK_B, PPB)
-    m_scr,  # (BLOCK_B, group)
+    m_scr,  # (BLOCK_B, Hq)
     l_scr,
-    acc_scr,  # (BLOCK_B, group, D)
+    acc_scr,  # (BLOCK_B, Hq, D)
     *,
     scale: float,
     page_tokens: int,
     n_pages: int,
     block_b: int,
     pages_per_block: int,
+    group: int,
 ):
     bb = pl.program_id(0)
-    h = pl.program_id(1)
-    pb = pl.program_id(2)
-    npb = pl.num_programs(2)
+    pb = pl.program_id(1)
+    npb = pl.num_programs(1)
     T = page_tokens
+    Hkv = k_buf.shape[4]
 
     def issue(slot, blk):
         """One DMA burst: every (request, page) of one page block."""
@@ -97,11 +106,11 @@ def _pa_kernel(
                 gp = jnp.minimum(blk * pages_per_block + j, n_pages - 1)
                 page = table_ref[gb * n_pages + gp]
                 pltpu.make_async_copy(
-                    k_hbm.at[page, :, h, :], k_buf.at[slot, i, j],
+                    k_hbm.at[page], k_buf.at[slot, i, j],
                     sems.at[0, slot, i, j],
                 ).start()
                 pltpu.make_async_copy(
-                    v_hbm.at[page, :, h, :], v_buf.at[slot, i, j],
+                    v_hbm.at[page], v_buf.at[slot, i, j],
                     sems.at[1, slot, i, j],
                 ).start()
 
@@ -109,12 +118,10 @@ def _pa_kernel(
         for i in range(block_b):
             for j in range(pages_per_block):
                 pltpu.make_async_copy(
-                    k_hbm.at[0, :, h, :], k_buf.at[slot, i, j],
-                    sems.at[0, slot, i, j],
+                    k_hbm.at[0], k_buf.at[slot, i, j], sems.at[0, slot, i, j],
                 ).wait()
                 pltpu.make_async_copy(
-                    v_hbm.at[0, :, h, :], v_buf.at[slot, i, j],
-                    sems.at[1, slot, i, j],
+                    v_hbm.at[0], v_buf.at[slot, i, j], sems.at[1, slot, i, j],
                 ).wait()
 
     @pl.when(pb == 0)
@@ -132,9 +139,19 @@ def _pa_kernel(
     slot = pb % 2
     wait(slot)
 
+    R = T * Hkv  # rows of one page: token-major, head-minor
+    Hq = q_ref.shape[1]
+    # score column c is token c // Hkv of KV head c % Hkv; query head r
+    # reads KV head r // group
+    col = lax.broadcasted_iota(jnp.int32, (Hq, R), 1)
+    same_head = lax.broadcasted_iota(jnp.int32, (Hq, R), 0) // group == (
+        col % Hkv
+    )
+    col_tok = col // Hkv
+    row_tok = lax.broadcasted_iota(jnp.int32, (R, k_buf.shape[5]), 0) // Hkv
     for i in range(block_b):
         gb = bb * block_b + i
-        q = q_ref[i].astype(jnp.float32) * scale  # (G, D)
+        q = q_ref[i].astype(jnp.float32) * scale  # (Hq, D)
         m_prev = m_scr[i, :]
         l_prev = l_scr[i, :]
         acc = acc_scr[i]
@@ -142,20 +159,17 @@ def _pa_kernel(
         # bit-identical across (block_b, pages_per_block) settings
         for j in range(pages_per_block):
             gp = pb * pages_per_block + j
-            k = k_buf[slot, i, j].astype(jnp.float32)  # (T, D)
-            v = v_buf[slot, i, j].astype(jnp.float32)
+            k = k_buf[slot, i, j].astype(jnp.float32).reshape(R, -1)
+            v = v_buf[slot, i, j].astype(jnp.float32).reshape(R, -1)
             s = lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # (G, T)
-            kpos = gp * T + lax.broadcasted_iota(
-                jnp.int32, s.shape, dimension=1
-            )
-            mask = kpos < len_ref[gb]
+            )  # (Hq, R)
+            mask = same_head & (gp * T + col_tok < len_ref[gb])
             # mask BEFORE the running max and zero V at masked slots:
             # garbage (even NaN) in padded pages never reaches the output
             s = jnp.where(mask, s, NEG_INF)
-            v = jnp.where(mask[0][:, None], v, 0.0)
+            v = jnp.where(gp * T + row_tok < len_ref[gb], v, 0.0)
             m_new = jnp.maximum(m_prev, s.max(axis=-1))
             pexp = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
             alpha = jnp.exp(m_prev - m_new)
@@ -179,7 +193,7 @@ def _pa_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "pages_per_block", "block_b", "interpret"),
+    static_argnames=("scale", "pages_per_block", "block_b"),
 )
 def paged_attention(
     q: jax.Array,
@@ -191,7 +205,6 @@ def paged_attention(
     scale: Optional[float] = None,
     pages_per_block: Optional[int] = None,
     block_b: Optional[int] = None,
-    interpret: bool = True,
 ) -> jax.Array:
     """Decode attention over a paged KV pool.
 
@@ -248,38 +261,38 @@ def paged_attention(
         n_pages=NP,
         block_b=blk_b,
         pages_per_block=ppb,
+        group=group,
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(Bp // blk_b, Hkv, npb),
+            grid=(Bp // blk_b, npb),
             in_specs=[
                 pl.BlockSpec(
-                    (blk_b, group, D),
-                    lambda b, h, p, table, lens: (b, h, 0),
+                    (blk_b, Hq, D), lambda b, p, table, lens: (b, 0, 0)
                 ),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
-                (blk_b, group, D), lambda b, h, p, table, lens: (b, h, 0)
+                (blk_b, Hq, D), lambda b, p, table, lens: (b, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((2, blk_b, ppb, T, D), k_pages.dtype),
-                pltpu.VMEM((2, blk_b, ppb, T, D), v_pages.dtype),
+                pltpu.VMEM((2, blk_b, ppb, T, Hkv, D), k_pages.dtype),
+                pltpu.VMEM((2, blk_b, ppb, T, Hkv, D), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2, blk_b, ppb)),
-                pltpu.VMEM((blk_b, group), jnp.float32),
-                pltpu.VMEM((blk_b, group), jnp.float32),
-                pltpu.VMEM((blk_b, group, D), jnp.float32),
+                pltpu.VMEM((blk_b, Hq), jnp.float32),
+                pltpu.VMEM((blk_b, Hq), jnp.float32),
+                pltpu.VMEM((blk_b, Hq, D), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((Bp, Hq, D), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="paged_attention_decode",
     )(table.reshape(-1), lens, qq, k_pages, v_pages)
     return out[:B]

@@ -20,7 +20,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 __all__ = ["gated_linear_scan"]
 
@@ -44,7 +44,7 @@ def _lru_kernel(a_ref, b_ref, y_ref, h_scr, *, block_s: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_d", "block_s", "interpret")
+    jax.jit, static_argnames=("block_d", "block_s")
 )
 def gated_linear_scan(
     a: jax.Array,
@@ -52,7 +52,6 @@ def gated_linear_scan(
     *,
     block_d: int = 512,
     block_s: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """y_t = a_t * y_{t-1} + b_t along axis 1.
 
@@ -77,9 +76,9 @@ def gated_linear_scan(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, S, D), b.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="rglru_gated_linear_scan",
     )(a, b)

@@ -25,7 +25,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.kernels import mode
 
 __all__ = ["selective_scan"]
 
@@ -68,7 +68,7 @@ def _ssm_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_d", "block_s", "interpret")
+    jax.jit, static_argnames=("block_d", "block_s")
 )
 def selective_scan(
     x: jax.Array,
@@ -80,7 +80,6 @@ def selective_scan(
     *,
     block_d: int = 512,
     block_s: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """Fused mamba1 scan.
 
@@ -122,9 +121,9 @@ def selective_scan(
         ),
         out_shape=jax.ShapeDtypeStruct((B, S, Di), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, block_d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=compat.tpu_interpret(interpret),
+        interpret=mode.interpret(),
         name="mamba1_selective_scan",
     )(x, dt, at, b, c, drow)

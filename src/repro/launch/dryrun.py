@@ -111,7 +111,7 @@ def build_ctx(mesh, *, attn_chunk: int = 512, remat: str = "full",
     return RunCtx(
         mesh=mesh, dp=dp, tp=tp, remat=remat, moe_mode=moe_mode,
         attn_impl="chunked", attn_chunk=attn_chunk, scan_impl=scan_impl,
-        interpret=True, fsdp_gather=fsdp_gather,
+        fsdp_gather=fsdp_gather,
         seq_shard_acts=seq_shard_acts,
     )
 

@@ -18,9 +18,11 @@ builds the per-rank backend tuple that ``repro.core.engine.make_engine``
 """
 from __future__ import annotations
 
-from typing import Iterable, Optional, Tuple
+import math
+from typing import Any, Iterable, Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
     "make_production_mesh",
@@ -37,12 +39,27 @@ __all__ = [
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh for tests / elastic restarts."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(
+    shape: Tuple[int, ...],
+    axes: Tuple[str, ...],
+    devices: Optional[Sequence[Any]] = None,
+) -> jax.sharding.Mesh:
+    """Every mesh of the repo is built here, over ``devices`` (default: the
+    first ``prod(shape)`` of ``jax.devices()``).
+
+    Axis types are ``Auto``: the model code leaves placement to the GSPMD
+    partitioner and the GAS layer runs inside ``shard_map``, neither of
+    which types its values (``jax.make_mesh`` defaults to ``Explicit``).
+    """
+    if devices is None:
+        devices = jax.devices()[: math.prod(shape)]
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def mesh_axes(mesh: jax.sharding.Mesh) -> Tuple[Tuple[str, ...], str]:

@@ -15,7 +15,7 @@ Roles (``--role``):
   pool optionally on its own engine via ``EngineMap``); finished KV caches
   cross over the GAS layer with ``sched.plan_p2p``-planned segmented puts
   and an AM request/reply control plane (``repro.serving.disagg``).
-  Needs >= 2 host devices (set ``XLA_FLAGS`` before JAX imports).
+  Needs one device per rank.
 - ``decode`` — the colocated path: one node prefills and decodes
   (:class:`Server` continuous batching, unchanged).  With ``--paged`` the
   KV cache lives in the global paged pool (:class:`PagedServer`): pages
@@ -24,7 +24,10 @@ Roles (``--role``):
 - ``prefill`` — the prefill pool alone: computes prefills and reports KV
   blocks/s, the feeder-side capacity number.
 
-CPU-scale demo: ``python -m repro.launch.serve --arch qwen3-4b --smoke``.
+``--arch`` builds the architecture at its published widths with random
+weights from a fixed seed; ``--smoke`` builds its small test preset
+instead.  CPU-scale demo: ``python -m repro.launch.serve --smoke`` (with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for N ranks).
 """
 import argparse
 import dataclasses
@@ -101,6 +104,7 @@ def _paged_decode_views_fn(model, ctx, layout):
 
 
 _PATCH_CHUNK = 8
+_READ_CHUNK = 32
 
 
 def _pool_patch_fn(layout):
@@ -126,6 +130,22 @@ def _pool_patch_fn(layout):
         return jax.tree_util.tree_map(apply, views, rowviews)
 
     return patch
+
+
+def _pool_read_fn(layout):
+    """Jitted read of ``idx`` pages of the views-resident pool back into
+    carrier rows ``(len(idx), page_elems)``: the host sync reads the pool
+    a chunk at a time, so the float32 carrier of the whole pool is never
+    materialised on the device."""
+    import jax
+
+    @jax.jit
+    def read(views, idx):
+        return layout.views_to_pool(
+            jax.tree_util.tree_map(lambda pool: pool[:, idx], views)
+        )
+
+    return read
 
 
 def _pool_write_need(store, layout, rid: int, position: int) -> int:
@@ -465,6 +485,7 @@ class PagedServer(Server):
         # src->dst splits, applied by the jitted patch program before the
         # next decode step (or before any host sync)
         self._patch = _pool_patch_fn(self.layout)
+        self._read = _pool_read_fn(self.layout)
         self._pending_rows: Dict[int, np.ndarray] = {}
         self._pending_copies: List[tuple] = []
 
@@ -511,9 +532,20 @@ class PagedServer(Server):
             if self._pending_rows or self._pending_copies:
                 self._apply_pending()
             P = self.store.state.n_pages
-            mem = self.layout.views_to_pool(self._dev_views)
-            self.store.mem[:] = np.asarray(mem)[:P]
+            for lo in range(0, P, _READ_CHUNK):
+                idx = np.minimum(
+                    np.arange(lo, lo + _READ_CHUNK, dtype=np.int32), P
+                )  # a ragged tail reads the scratch page
+                rows = np.asarray(
+                    self._read(self._dev_views, self.jnp.asarray(idx))
+                )
+                self.store.mem[lo : lo + _READ_CHUNK] = rows[: P - lo]
             self._dev_views = None
+
+    def _live_pages(self) -> np.ndarray:
+        """Pages some request references; free pages hold nothing that
+        is ever read (a page is rewritten whole when it is allocated)."""
+        return np.flatnonzero(np.asarray(self.store.state.refcnt) > 0)
 
     # ------------------------------------------------------------------ #
     def submit(self, req: Request) -> None:
@@ -839,12 +871,22 @@ class PagedServer(Server):
         swaps in a head-sharded stacked carrier and a ``shard_map``-ped
         step without touching the scheduler loop above."""
         if self._dev_views is None:  # (re-)upload the mutated host mirror
-            self._dev_views = self.layout.decode_views(self.jnp.asarray(
-                np.concatenate(
-                    [self.store.mem, self.layout.empty_page_row()[None]],
-                    axis=0,
+            # empty pages on the device, then the live ones as patches:
+            # the pool crosses in page chunks, never as one float32 carrier
+            P = self.store.state.n_pages
+            empty = self.layout.decode_views(
+                self.jnp.asarray(self.layout.empty_page_row()[None])
+            )
+            self._dev_views = self.jax.tree_util.tree_map(
+                lambda e: self.jnp.broadcast_to(
+                    e, e.shape[:1] + (P + 1,) + e.shape[2:]
+                ),
+                empty,
+            )
+            for page in self._live_pages():
+                self._pending_rows.setdefault(
+                    int(page), self.store.mem[page].copy()
                 )
-            ))
         if self._pending_rows or self._pending_copies:
             self._apply_pending()
         logits, self._dev_views = self._decode_paged(
@@ -902,9 +944,9 @@ def _tp_paged_decode_fn(model, ctx, shard_layout, tp: int, backend,
     recursive-doubling schedules commute)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core import sched as core_sched
     from repro.core.engine import make_engine
     from repro.parallel.tp import TPGroup
@@ -915,7 +957,7 @@ def _tp_paged_decode_fn(model, ctx, shard_layout, tp: int, backend,
         params = jax.tree_util.tree_map(lambda x: x[0], params)
         mem = mem[0]
         mem = mem.at[mem.shape[0] - 1].set(jnp.asarray(empty_row, mem.dtype))
-        engine = make_engine(backend, "tp", tp, interpret=ctx.interpret)
+        engine = make_engine(backend, "tp", tp)
         group = TPGroup(
             tp, lambda x: core_sched.all_reduce(engine, x, costs=costs)
         )
@@ -971,22 +1013,22 @@ class TPPagedServer(PagedServer):
                  sched_cost_table: Optional[Dict[str, Any]] = None, **kw):
         super().__init__(model, ctx, params, batch_size, cache_len, **kw)
         import jax
-        from jax.sharding import Mesh, NamedSharding
+        from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
+        from repro.launch.mesh import make_mesh
         from repro.parallel import tp as tp_lib
 
         tp_lib.validate_tp(model.cfg, tp)
         if jax.device_count() < tp:
             raise ValueError(
-                f"tp={tp} needs {tp} devices, have {jax.device_count()} "
-                f"(set --xla_force_host_platform_device_count)"
+                f"tp={tp} needs {tp} devices, have {jax.device_count()}"
             )
         self.tp = tp
         self.shard_layout, self.shard_cols = self.layout.shard_heads(
             tp, model.cfg.n_kv_heads
         )
-        self._tp_mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+        self._tp_mesh = make_mesh((tp,), ("tp",))
         self._tp_sharding = NamedSharding(self._tp_mesh, P("tp"))
         self._stacked_params = jax.device_put(
             tp_lib.stack_shards(params, tp), self._tp_sharding
@@ -1229,16 +1271,17 @@ class TPPooledDecodeServer(PooledDecodeServer):
                          store=store, eos_id=eos_id, greedy=greedy,
                          seed=seed, on_page_shortage=on_page_shortage)
         import jax
-        from jax.sharding import Mesh, NamedSharding
+        from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
+        from repro.launch.mesh import make_mesh
         from repro.parallel import tp as tp_lib
 
         tp_lib.validate_tp(model.cfg, tp)
         self.tp = tp
         self.shard_mems = shard_mems  # cluster-owned, re-aliased in place
         if tp_mesh is None:
-            tp_mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+            tp_mesh = make_mesh((tp,), ("tp",))
         self._tp_sharding = NamedSharding(tp_mesh, P("tp"))
         self._stacked_params = jax.device_put(
             tp_lib.stack_shards(params, tp), self._tp_sharding
@@ -1277,7 +1320,9 @@ class TPPooledDecodeServer(PooledDecodeServer):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="the architecture's small test preset instead of "
+                         "its published widths")
     ap.add_argument("--role", choices=("prefill", "decode", "memory", "both"),
                     default="both",
                     help="both = disaggregated cluster (prefill pool + "
@@ -1323,25 +1368,21 @@ def main() -> None:
                          "(e.g. 'xla,gascore')")
     args = ap.parse_args()
 
-    if args.role == "both" or (args.role == "decode" and args.tp > 1):
-        import os
-
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            "--xla_force_host_platform_device_count="
-            f"{max(args.n_prefill + args.n_decode + args.n_memory, args.tp)}",
-        )
-
     import jax
 
-    from repro.configs.registry import SMOKE
+    from repro.configs.registry import ARCHS, SMOKE
+    from repro.launch import compile_cache
     from repro.models.build import build_model
     from repro.parallel.ctx import RunCtx
 
-    cfg = SMOKE[args.arch]
+    compile_cache.enable()
+    dev = jax.devices()[0]
+    print(f"platform: {dev.platform} device_kind: {dev.device_kind} "
+          f"devices: {jax.device_count()}")
+    cfg = (SMOKE if args.smoke else ARCHS)[args.arch]
     model = build_model(cfg)
     ctx = RunCtx(mesh=None, remat="none")
-    params, _ = model.init(ctx, jax.random.PRNGKey(0))
+    params = jax.jit(lambda k: model.init(ctx, k)[0])(jax.random.PRNGKey(0))
 
     rng = np.random.default_rng(0)
     reqs = [

@@ -30,10 +30,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from jax import shard_map
+
+from repro.kernels import mode as kmode
 from repro.kernels import ops
 from repro.models.common import ArchConfig
 from repro.parallel.ctx import RunCtx, use_weight
-from repro.compat import shard_map
 
 Params = Dict[str, Any]
 
@@ -283,7 +285,7 @@ def apply_attention(
             out = ops.attention(
                 jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
                 jnp.moveaxis(v, 1, 2), causal=causal, window=window,
-                scale=scale, impl="pallas", interpret=ctx.interpret,
+                scale=scale, impl="pallas",
             )
             out = jnp.moveaxis(out, 1, 2)
         else:
@@ -326,8 +328,7 @@ def apply_attention(
         pp = pp.at[phys, slot].set(pos)
         out = ops.paged_attention(
             q[:, 0], kp, vp, page_table, pos + 1, scale=scale,
-            impl="pallas" if ctx.attn_impl == "pallas" else "ref",
-            interpret=ctx.interpret,
+            impl=kmode.decode_attn_impl(ctx.attn_impl),
         )[:, None]
         new_cache = {"k": kp, "v": vp, "pos": pp}
     elif mode == "decode":
@@ -469,7 +470,7 @@ def _moe_ep(p, cfg: ArchConfig, ctx: RunCtx, x2d: jax.Array):
     data_axes = tuple(a for a in ctx.dp if a == "data")
 
     def body(x_l, router_w, wi, wg, wo):
-        eng = make_engine(ctx.moe_backend, tp, tp_size, interpret=ctx.interpret)
+        eng = make_engine(ctx.moe_backend, tp, tp_size)
         if data_axes:
             # FSDP unshard-at-use for expert weights (explicit all-gather
             # over the data axis INSIDE the EP region; its transpose is the
@@ -666,7 +667,7 @@ def apply_mamba(
     else:
         y = ops.selective_scan(
             xin, dt, a, bmat, cmat, p["d_skip"],
-            impl=ctx.scan_impl, interpret=ctx.interpret,
+            impl=ctx.scan_impl,
         )
         if mode == "prefill":
             # final SSM state for decode continuation (exact oracle scan;
@@ -773,9 +774,7 @@ def apply_rec(
         y = hnew[:, None, :]
         new_cache = {"conv": new_conv, "h": hnew}
     else:
-        y = ops.gated_linear_scan(
-            a, b, impl=ctx.scan_impl, interpret=ctx.interpret
-        )
+        y = ops.gated_linear_scan(a, b, impl=ctx.scan_impl)
         new_cache = (
             {"conv": new_conv, "h": y[:, -1, :].astype(jnp.float32)}
             if mode == "prefill"

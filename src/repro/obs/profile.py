@@ -1,4 +1,4 @@
-"""Device-time kernel profiling: the measurement side of the cost loop.
+"""Kernel profiling by timed re-execution: the measurement side of the cost loop.
 
 PR 9's tracer records host walls *around* initiation and sync — good
 enough to fit α/β from end-to-end transfer spans, but blind to the one
@@ -12,17 +12,15 @@ program *alone*, at several sizes, and hand its per-KiB slope to
 
 Measurement discipline:
 
-- **On-device events where available.**  A backend with real device
-  timers (a TPU) could stamp kernel launch/retire on device; the forced
-  host-platform runs this repo's CI uses (and interpret-mode Pallas)
-  have none, so the profiler falls back to *interleaved timed
-  re-execution*: run the target repeatedly under ``perf_counter`` with
-  ``block_until_ready`` fencing each call, interleaving targets
-  round-robin so machine-load drift lands on all of them equally, and
-  keep the best-of-N (scheduler noise only ever adds time).
-- **Honest labelling.**  Every recorded sample carries
-  ``measured="device"`` or ``measured="wall"`` so a consumer (the
-  bench artifact, a fit) knows which clock produced it.
+- **Interleaved timed re-execution.**  Run the target repeatedly
+  under ``perf_counter`` with ``block_until_ready`` fencing each call,
+  interleaving targets round-robin so machine-load drift lands on all
+  of them equally, and keep the best-of-N (scheduler noise only ever
+  adds time).
+- **Honest labelling.**  Every sample is a host wall clock around the
+  call, on any backend, and carries ``measured="wall"``; device time
+  comes only from a profiler trace of the device, which this module
+  does not read.
 - **Never on the serving hot path.**  Profiling is an offline,
   explicit re-execution of a target — the ``obs_overhead`` gate
   (< 1.02x with tracing on, profiler idle) is unaffected by anything
@@ -40,23 +38,8 @@ from repro.obs import trace as obs_trace
 
 __all__ = [
     "DeviceProfiler",
-    "device_events_available",
     "measure",
 ]
-
-
-def device_events_available() -> bool:
-    """True when the backend exposes on-device event timers.
-
-    The forced host-platform (CPU) backend — where interpret-mode
-    Pallas runs — does not; profiled samples are then wall-clocked
-    re-executions, marked ``measured="wall"``."""
-    try:
-        import jax
-
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # pragma: no cover - jax always importable here
-        return False
 
 
 def _block(x: Any) -> Any:
@@ -79,7 +62,7 @@ def measure(
     """Time ``fn()`` by re-execution: ``warmup`` unrecorded calls (JIT
     compile + cache warm), then ``iters`` timed calls, each fenced with
     ``block_until_ready``.  Returns ``(best_us, measured)`` where
-    ``measured`` names the clock (``"device"`` | ``"wall"``)."""
+    ``measured`` names the clock: always ``"wall"``, the host's."""
     for _ in range(max(warmup, 0)):
         _block(fn())
     best = None
@@ -88,7 +71,7 @@ def measure(
         _block(fn())
         dt = (time.perf_counter() - t0) * 1e6
         best = dt if best is None or dt < best else best
-    return best, ("device" if device_events_available() else "wall")
+    return best, "wall"
 
 
 class DeviceProfiler:
@@ -158,10 +141,9 @@ class DeviceProfiler:
                 dt = (time.perf_counter() - t0) * 1e6
                 if name not in best or dt < best[name]:
                     best[name] = dt
-        measured = "device" if device_events_available() else "wall"
         for name, _, tags in norm:
             self._emit({"name": name, "dur_us": round(best[name], 3),
-                        "measured": measured, **tags})
+                        "measured": "wall", **tags})
         return best
 
     def profile_epilogue(

@@ -32,11 +32,12 @@ class RunCtx:
     # implementation switches (software <-> hardware migration points)
     moe_mode: str = "auto"  # auto | ep_shardmap | local
     moe_backend: str = "xla"  # xla | gascore
-    attn_impl: str = "chunked"  # chunked | pallas
+    # chunked | pallas; None lets repro.kernels.mode pick the paged
+    # decode kernel from the platform (train/prefill stay chunked)
+    attn_impl: Optional[str] = None
     attn_chunk: int = 512
     scan_impl: str = "ref"  # ref | pallas
     remat: str = "full"  # none | full | dots
-    interpret: bool = True
     # §Perf iteration A: constrain weights to their FSDP-gathered form at
     # the point of use, so the partitioner all-gathers the (small) weight
     # shard instead of all-reducing the (huge) activations.  False =

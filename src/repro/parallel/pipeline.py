@@ -37,7 +37,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import CommEngine, XlaEngine, wait_all
-from repro.compat import shard_map
+from jax import shard_map
 
 __all__ = ["gpipe", "pipelined"]
 

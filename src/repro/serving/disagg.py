@@ -125,7 +125,6 @@ class DisaggCluster:
         prefill_backend: str = "xla",
         decode_backend: str = "xla",
         memory_backend: str = "xla",
-        interpret: bool = True,
         node_axis: str = "node",
         eos_id: int = -1,
         costs: Optional[Dict[str, Any]] = None,
@@ -147,8 +146,9 @@ class DisaggCluster:
         import jax
         import jax.numpy as jnp
 
+        from jax import shard_map
+
         from repro.core import am, gasnet, sched
-        from repro.compat import shard_map
         from repro.launch.serve import (
             PooledDecodeServer, Server, TPPooledDecodeServer,
         )
@@ -181,6 +181,7 @@ class DisaggCluster:
         self.flight_ticks = flight_ticks
         self.flight_dumps: List[Dict[str, Any]] = []
         self.model, self.ctx, self.params = model, ctx, params
+        self._rank_params: Dict[int, Any] = {}
         self.n_prefill, self.n_decode = n_prefill, n_decode
         self.n_memory = n_memory
         self.n_spare = n_spare
@@ -195,7 +196,6 @@ class DisaggCluster:
         self.tp = tp
         self.tp_backend = tp_backend or decode_backend
         self.n_groups = n_decode // tp if tp else n_decode
-        self._interpret = interpret
         self._decode_batch = decode_batch
         self._eos_id = eos_id
 
@@ -216,7 +216,6 @@ class DisaggCluster:
             self.mesh,
             node_axis=node_axis,
             backend=backends,
-            interpret=interpret,
             am_capacity=self.max_done + 4,
             am_payload_width=1,
         )
@@ -354,19 +353,17 @@ class DisaggCluster:
         # PagedServer; the dense Server survives only as the oracle for
         # the unpaged (paged=False) handoff.
         if paged and tp > 1:
-            from jax.sharding import Mesh
-
             devices = jax.devices()
             self.decode_servers = [
                 TPPooledDecodeServer(
                     model, ctx, params, decode_batch, cache_len,
                     store=self.stores[g], shard_mems=self.shard_mems[g],
                     tp=tp, tp_backend=self.tp_backend,
-                    tp_mesh=Mesh(
-                        np.array(devices[
+                    tp_mesh=mesh_lib.make_mesh(
+                        (tp,), ("tp",),
+                        devices=devices[
                             self.decode_rank(g): self.decode_rank(g) + tp
-                        ]),
-                        ("tp",),
+                        ],
                     ),
                     costs=costs, eos_id=eos_id,
                     on_page_shortage=(
@@ -379,7 +376,8 @@ class DisaggCluster:
         elif paged:
             self.decode_servers = [
                 PooledDecodeServer(
-                    model, ctx, params, decode_batch, cache_len,
+                    model, ctx, self._params_on(self.decode_rank(d)),
+                    decode_batch, cache_len,
                     store=self.stores[d], eos_id=eos_id,
                     on_page_shortage=(
                         lambda rid, need, d=d:
@@ -478,6 +476,15 @@ class DisaggCluster:
 
     def memory_rank(self, m: int) -> int:
         return self._memory_base + m
+
+    def _params_on(self, rank: int) -> Any:
+        """The weights on ``rank``'s own device: each rank's model compute
+        runs on its own chip (inputs from the host follow the weights)."""
+        if rank not in self._rank_params:
+            self._rank_params[rank] = self.jax.device_put(
+                self.params, self.mesh.devices.flat[rank]
+            )
+        return self._rank_params[rank]
 
     def _group_down(self, g: int) -> bool:
         """True when any member rank of decode group ``g`` is killed or
@@ -779,7 +786,7 @@ class DisaggCluster:
             ):
                 toks = jnp.asarray(req.prompt, jnp.int32)[None]
                 logits, caches_one = self._prefill_fn(
-                    self.params, {"inputs": toks}
+                    self._params_on(p), {"inputs": toks}
                 )
                 tok = int(np.argmax(np.asarray(logits)[0]))
             if not req.out:  # a recompute-resume already holds its tokens
@@ -1602,9 +1609,7 @@ class DisaggCluster:
         )
         if not alive:
             return
-        engine = engine_lib.make_engine(
-            alive, self.node_axis, len(alive), interpret=self._interpret
-        )
+        engine = engine_lib.make_engine(alive, self.node_axis, len(alive))
         if self.paged:
             self.plan = sched.plan_p2p(
                 nbytes=self.shard_layout.page_bytes,
@@ -1655,8 +1660,9 @@ class DisaggCluster:
         self.n_groups += 1
         self.decode_servers.append(
             PooledDecodeServer(
-                self.model, self.ctx, self.params, self._decode_batch,
-                self.cache_len, store=store, eos_id=self._eos_id,
+                self.model, self.ctx, self._params_on(spare),
+                self._decode_batch, self.cache_len, store=store,
+                eos_id=self._eos_id,
                 on_page_shortage=(
                     lambda rid, need, g=g:
                     self._decode_shortage(g, rid, need)

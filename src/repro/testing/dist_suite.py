@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def main() -> None:
@@ -24,7 +25,7 @@ def main() -> None:
     from repro.parallel.pipeline import gpipe
     from repro.runtime.trainer import Trainer, TrainerConfig
 
-    mesh8 = jax.make_mesh((8,), ("node",))
+    mesh8 = make_mesh((8,), ("node",))
 
     # ---- int8 EF compressed all-reduce ------------------------------------
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 1024)), jnp.float32)
@@ -93,7 +94,7 @@ def main() -> None:
 
     # generous capacity so local-vs-EP drop boundaries rarely differ
     cfg = dataclasses.replace(SMOKE["arctic-480b"], capacity_factor=4.0)
-    mesh2d = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2d = make_mesh((2, 4), ("data", "model"))
     ctx_ep = RunCtx(mesh=mesh2d, dp=("data",), tp="model",
                     moe_mode="ep_shardmap", remat="none")
     ctx_lo = RunCtx(mesh=None, moe_mode="local", remat="none")
@@ -116,7 +117,7 @@ def main() -> None:
     cfg = SMOKE["qwen3-4b"]
     model = build_model(cfg)
     opt = adamw.AdamWConfig(lr=1e-3, weight_decay=0.0)
-    meshA = jax.make_mesh((4, 2), ("data", "model"))
+    meshA = make_mesh((4, 2), ("data", "model"))
     ctxA = RunCtx(mesh=meshA, dp=("data",), tp="model", remat="none")
     with tempfile.TemporaryDirectory() as td:
         trA = Trainer(model, ctxA, opt,
@@ -129,7 +130,7 @@ def main() -> None:
         loader.close()
 
         # "pod loss": restart on a smaller mesh from step 3's snapshot
-        meshB = jax.make_mesh((2, 2), ("data", "model"))
+        meshB = make_mesh((2, 2), ("data", "model"))
         ctxB = RunCtx(mesh=meshB, dp=("data",), tp="model", remat="none")
         trB = Trainer(model, ctxB, opt,
                       TrainerConfig(steps=6, ckpt_every=0, ckpt_dir=td,
@@ -153,7 +154,7 @@ def main() -> None:
 
     cfgq = SMOKE["qwen3-4b"]
     modelq = build_model(cfgq)
-    meshQ = jax.make_mesh((4, 2), ("data", "model"))
+    meshQ = make_mesh((4, 2), ("data", "model"))
     base_ctx = RunCtx(mesh=meshQ, dp=("data",), tp="model", remat="full")
     opt_ctx = _dc.replace(base_ctx, fsdp_gather=True, remat="names")
     pq, _ = modelq.init(RunCtx(mesh=None), jax.random.PRNGKey(2))

@@ -8,14 +8,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def main() -> None:
     from repro.core import am, collectives, gasnet
     from repro.core.engine import make_engine
 
-    mesh = jax.make_mesh((8,), ("node",))
+    mesh = make_mesh((8,), ("node",))
     ctx = gasnet.Context(mesh, node_axis="node", backend="xla",
                          am_payload_width=4)
 
@@ -82,7 +83,7 @@ def main() -> None:
     print("ring collectives OK")
 
     # ---- hierarchical all-reduce (2 pods x 4) -----------------------------
-    mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh2 = make_mesh((2, 4), ("pod", "data"))
     xx = jnp.arange(8.0 * 16).reshape(2, 4, 16) / 7.0
 
     def prog_hier(x_l):

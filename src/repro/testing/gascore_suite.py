@@ -9,7 +9,117 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
+
+
+BACKENDS = ("xla", "gascore", "xla,gascore")
+
+
+def engine_parity(n: int = 4) -> dict:
+    """The GAS layer's main operations on ``n`` devices for every engine
+    (``xla``, ``gascore`` and the mixed map), required to agree bit for
+    bit.  Values are small integers in float32, so any summation order
+    gives the exact sum: a difference can only be a transport fault.
+
+    Covers put, get, vectored get, vectored put, the AM request/reply
+    round trip, the planned all-reduce and each engine's own all-reduce.
+    Returns ``{op: result}`` of the ``xla`` engine."""
+    from repro.core import am, gasnet, sched
+
+    mesh = make_mesh((n,), ("node",))
+    seg0 = (jnp.arange(n * 256, dtype=jnp.float32) % 97).reshape(n, 256)
+    xs = (jnp.arange(n * n * 8 * 128, dtype=jnp.float32) % 13).reshape(
+        n, n * 8, 128
+    )
+
+    def run(backend):
+        ctx = gasnet.Context(mesh, node_axis="node", backend=backend,
+                             am_payload_width=4)
+
+        def pong(state, payload, args):
+            out = dict(state)
+            out["ack_payload"] = payload
+            out["ack_arg"] = state["ack_arg"] + args[0]
+            return out
+
+        pong_id = ctx.handlers.register("pong", pong)
+
+        def ping(state, payload, args):
+            out = dict(state)
+            out["got"] = state["got"] + args[0]
+            return out, am.reply_medium(
+                pong_id, payload * 2.0, args=(args[0] + 1,)
+            )
+
+        ctx.handlers.register("ping", ping, replies=True)
+
+        def prog(node, seg, x):
+            me = node.my_id
+            out = {}
+            out["put"] = node.put(
+                seg, jnp.full((128,), 1.0 + me, jnp.float32),
+                to=gasnet.Shift(1), index=64,
+            )
+            out["get"] = node.get(
+                seg, frm=gasnet.Shift(n - 1), index=128, size=128
+            )[None]
+            out["get_v"] = node.get_v(
+                seg, frm=gasnet.Shift(1), indices=[0, 128], size=128
+            )[None]
+            datas = jnp.stack(
+                [jnp.full((128,), 3.0 + me + j, jnp.float32) for j in range(2)]
+            )
+            out["put_v"] = node.put_v(
+                seg, datas, to=gasnet.Shift(2), indices=[0, 128],
+                pred=[True, (me % 2) == 0],
+            )
+            state = {
+                "got": jnp.zeros((), jnp.int32),
+                "ack_arg": jnp.zeros((), jnp.int32),
+                "ack_payload": jnp.zeros((4,), jnp.float32),
+            }
+            h = node.am_call(
+                (me + 1) % n, "ping",
+                payload=jnp.full((4,), 1.0 + me, jnp.float32),
+                args=(me * 5,), ack=lambda st: st["ack_payload"],
+            )
+            state = node.am_flush(state)
+            out["am"] = jnp.concatenate([
+                state["got"][None].astype(jnp.float32),
+                state["ack_arg"][None].astype(jnp.float32),
+                node.sync(h),
+            ])[None]
+            out["all_reduce_planned"] = sched.all_reduce(
+                node.engine, x[0]
+            )[None]
+            out["all_reduce"] = node.engine.all_reduce(x[0])[None]
+            return out
+
+        res = ctx.spmd(
+            prog, seg0, xs,
+            out_specs={k: P("node") for k in (
+                "put", "get", "get_v", "put_v", "am",
+                "all_reduce_planned", "all_reduce",
+            )},
+        )
+        return {k: np.asarray(v) for k, v in res.items()}
+
+    outs = {b: run(b) for b in BACKENDS}
+    want_ar = np.tile(np.asarray(xs).sum(0), (n, 1, 1))
+    for op in ("all_reduce_planned", "all_reduce"):
+        np.testing.assert_array_equal(outs["xla"][op], want_ar, err_msg=op)
+    for node in range(n):
+        am_row = outs["xla"]["am"][node]
+        assert am_row[0] == ((node - 1) % n) * 5, am_row
+        assert am_row[1] == node * 5 + 1, am_row
+        np.testing.assert_array_equal(am_row[2:], 2.0 * (1.0 + node))
+    for b in BACKENDS[1:]:
+        for op, want in outs["xla"].items():
+            np.testing.assert_array_equal(
+                outs[b][op], want, err_msg=f"{op}: {b} != xla"
+            )
+    return outs["xla"]
 
 
 def main() -> None:
@@ -19,7 +129,7 @@ def main() -> None:
     from repro.kernels import ref as kref
 
     N = 4
-    mesh = jax.make_mesh((N,), ("node",))
+    mesh = make_mesh((N,), ("node",))
 
     def run(fn, *args, in_specs=None, out_specs=P("node")):
         if in_specs is None:
@@ -81,11 +191,10 @@ def main() -> None:
     # "xla,gascore" is the heterogeneous EngineMap — alternating software
     # and hardware ranks in one mesh — and must pass the same parity suite
     # as each homogeneous engine.
-    BACKENDS = ("xla", "gascore", "xla,gascore")
     for op in ("all_reduce", "all_to_all", "all_gather", "reduce_scatter"):
         def make_prog(backend, op=op):
             def prog(a):
-                e = make_engine(backend, "node", N, interpret=True)
+                e = make_engine(backend, "node", N)
                 arg = a[0] if op != "all_gather" else a[0, :2]
                 return getattr(e, op)(arg)[None]
             return prog
@@ -106,7 +215,7 @@ def main() -> None:
 
     def coll_prog(backend):
         def prog(a):
-            e = make_engine(backend, "node", N, interpret=True)
+            e = make_engine(backend, "node", N)
             mono = collectives.ring_all_reduce(e, a[0])
             seg = collectives.segmented_ring_all_reduce(
                 e, a[0], n_segments=3, depth=2
@@ -136,7 +245,7 @@ def main() -> None:
     # split-phase primitives + the collectives built on them (Extended API)
     def nb_prog(backend):
         def prog(a):
-            e = make_engine(backend, "node", N, interpret=True)
+            e = make_engine(backend, "node", N)
             pending = e.shift_nb(a[0], 1)   # initiate
             local = a[0] * 2.0              # overlapped compute
             shifted = pending.wait()        # sync point
@@ -159,11 +268,10 @@ def main() -> None:
     # ---- vectored get parity: m slices per request/reply pair -------------
     from repro.core import am, gasnet
 
-    mesh_n = jax.make_mesh((N,), ("node",))
+    mesh_n = make_mesh((N,), ("node",))
 
     def run_getv(backend):
-        ctx_v = gasnet.Context(mesh_n, node_axis="node", backend=backend,
-                               interpret=True)
+        ctx_v = gasnet.Context(mesh_n, node_axis="node", backend=backend)
 
         def prog(node, seg):
             # plain vectored fetch from the left neighbour
@@ -203,8 +311,7 @@ def main() -> None:
 
     # ---- vectored put parity: m writes + command block per transfer -------
     def run_putv(backend):
-        ctx_p = gasnet.Context(mesh_n, node_axis="node", backend=backend,
-                               interpret=True)
+        ctx_p = gasnet.Context(mesh_n, node_axis="node", backend=backend)
 
         def prog(node, seg):
             datas = jnp.stack(
@@ -240,7 +347,7 @@ def main() -> None:
 
     def run_request_reply(backend):
         ctx_rr = gasnet.Context(mesh_n, node_axis="node", backend=backend,
-                                am_payload_width=4, interpret=True)
+                                am_payload_width=4)
         table = ctx_rr.handlers
 
         def pong(state, payload, args):
@@ -306,7 +413,7 @@ def main() -> None:
     # mixed engine maps within dtype tolerance
     def tp_prog(backend, dt):
         def prog(a):
-            e = make_engine(backend, "node", N, interpret=True)
+            e = make_engine(backend, "node", N)
             out = sched.all_reduce(e, a[0].astype(dt))
             return out.astype(jnp.float32)[None]
         return prog
@@ -331,6 +438,9 @@ def main() -> None:
                 err_msg=f"TP all-reduce engine parity vs {b}",
             )
     print("TP-group decode-payload all-reduce parity OK (f32+bf16)")
+
+    engine_parity(N)
+    print("bitwise engine parity OK (put/get/vectored/AM/all-reduce)")
 
     print("GASCORE_SUITE_PASS")
 

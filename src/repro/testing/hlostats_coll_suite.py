@@ -7,13 +7,14 @@ if __name__ == "__main__":
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def main() -> None:
     from repro.launch import hlostats
 
-    mesh = jax.make_mesh((4,), ("d",))
+    mesh = make_mesh((4,), ("d",))
     M, T = 256, 10
 
     def f(x, ws):

@@ -9,8 +9,9 @@ if __name__ == "__main__":
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 
 
 def main() -> None:
@@ -28,11 +29,11 @@ def main() -> None:
     # software, pure hardware, and heterogeneous engine maps.  At 2 ranks
     # every schedule is one exchange-and-add, so parity is BITWISE.
     TP = 2
-    mesh = Mesh(np.array(jax.devices()[:TP]), ("tp",))
+    mesh = make_mesh((TP,), ("tp",))
 
     def ar_prog(backend, dt):
         def prog(x):
-            e = make_engine(backend, "tp", TP, interpret=True)
+            e = make_engine(backend, "tp", TP)
             return sched.all_reduce(e, x[0].astype(dt))[None]
 
         return jax.jit(shard_map(prog, mesh=mesh, in_specs=(P("tp"),),

@@ -6,11 +6,12 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core.addrspace import AddressSpace, GlobalAddress
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import sanitize
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("node",))
+    return make_mesh((1,), ("node",))
 
 
 def test_register_alloc_read():
@@ -44,7 +45,7 @@ def test_bad_node_axis_rejected():
 
 
 def test_sanitize_single_and_tuple_axes():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     # size-1 axes always divide
     assert sanitize(P("model", None), (7, 3), mesh) == P("model", None)
     # unknown-dim specs pass through

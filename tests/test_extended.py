@@ -13,10 +13,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import extended, gasnet
 from repro.core.engine import Pending, XlaEngine
+from repro.launch.mesh import make_mesh
 
 
 def make_ctx():
-    mesh = jax.make_mesh((1,), ("node",))
+    mesh = make_mesh((1,), ("node",))
     return gasnet.Context(mesh, node_axis="node", backend="xla")
 
 
@@ -161,9 +162,9 @@ def test_try_sync_reports_done():
 
 def test_gpipe_runs_with_explicit_engine():
     from repro.parallel.pipeline import gpipe
-    from repro.compat import shard_map
+    from jax import shard_map
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     x = jnp.arange(4.0 * 2 * 3).reshape(4, 2, 3)  # (M, mb, d)
     w = jnp.eye(3) * 2.0
 

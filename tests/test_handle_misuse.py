@@ -17,12 +17,13 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import gasnet
 from repro.core.extended import AlreadyWaitedError
+from repro.launch.mesh import make_mesh
 
 ENGINES = ("xla", "gascore")
 
 
 def make_ctx(backend):
-    mesh = jax.make_mesh((1,), ("node",))
+    mesh = make_mesh((1,), ("node",))
     return gasnet.Context(mesh, node_axis="node", backend=backend)
 
 

@@ -348,8 +348,8 @@ def test_flash_attention_backward_vs_oracle(case):
     v = _rand((B, Hkv, S, D))
 
     def loss_kernel(q, k, v):
-        return (flash_attention_vjp(q, k, v, causal, window, None, 64, 64,
-                                    True) ** 2).sum()
+        return (flash_attention_vjp(q, k, v, causal, window, None, 64,
+                                    64) ** 2).sum()
 
     def loss_ref(q, k, v):
         return (ref.attention(q, k, v, causal=causal, window=window) ** 2).sum()

@@ -18,6 +18,7 @@ from repro.core import am
 from repro.core import collectives as coll
 from repro.core import sched
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 from repro.models.common import build_layer_program
 from repro.optim import adamw, compression
 from repro.parallel.sharding import sanitize
@@ -705,7 +706,7 @@ def test_sanitize_drops_nondividing_axes():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("model",))  # single device: model size 1
+    mesh = make_mesh((1,), ("model",))  # single device: model size 1
     # size-1 axes always divide; use shape math directly on a fake mesh-like
     spec = sanitize(P("model", None), (7, 3), mesh)
     assert spec == P("model", None)  # size-1 axis divides everything
