@@ -877,32 +877,25 @@ def obs_sections(report) -> None:
       over the same burst with the no-op recorder installed (the
       production default).  The enabled run bounds the instrumentation
       tax from above — the disabled path runs only the ``active()`` +
-      ``.enabled`` guards, which are strictly cheaper — so gating the
+      ``.enabled`` guards and the profiler host spans, which are
+      strictly cheaper — so gating the
       ratio (``check_serve_perf``: < 1.02x) keeps tracing-off overhead
       under the 2% budget by construction.
-    - device-timed kernel profiles (``DeviceProfiler``): the paged
-      attention hot kernel vs its oracle, and the server's fused decode
-      step, timed by interleaved re-execution (labelled
-      ``measured="wall"`` on the forced-host backend),
     - the cost-model feedback loop: real executed transfers (warmed,
       blocking segmented puts at three payload sizes) recorded as
-      ``cat="transfer"`` spans, PLUS the receiver epilogue (the
-      install/store a landed segment pays) timed alone at the same
-      sizes — so ``EngineCost.fit_from_trace`` refits (α, β) AND
-      decomposes the measured per-KiB slope into wire β vs epilogue γ.
-      Rows report the shipped DEFAULT model's predicted-vs-measured
-      error and the refit's residual — the measurement closing the loop
-      back into ``plan_p2p``/``plan_collective``.  Thin traces degrade
+      ``cat="transfer"`` spans, from which ``EngineCost.fit_from_trace``
+      refits (α, β).  Rows report the shipped DEFAULT model's
+      predicted-vs-measured error and the refit's residual — the
+      measurement closing the loop back into
+      ``plan_p2p``/``plan_collective``.  Thin traces degrade
       to a reported ``fit: insufficient-data`` note, never a crash.
     """
     from repro.configs.registry import SMOKE
     from repro.core import gasnet
     from repro.core import sched as core_sched
-    from repro.kernels import ops as kernel_ops
     from repro.launch.serve import PagedServer, Request
     from repro.models.build import build_model
     from repro.obs import trace as obs_trace
-    from repro.obs.profile import DeviceProfiler
     from repro.parallel.ctx import RunCtx
 
     ctx = RunCtx(mesh=None, remat="none")
@@ -954,37 +947,6 @@ def obs_sections(report) -> None:
            unit="x", op="obs_overhead", overhead_x=round(overhead, 4),
            wall_on_s=round(t_on, 4), wall_off_s=round(t_off, 4))
 
-    # ---- device-timed kernel profiles ------------------------------------- #
-    # The decode hot kernel vs its oracle, interleaved so load drift
-    # lands on both, then the server's fused decode step over live rows
-    # (offline timed re-execution — decode from fixed tables is
-    # idempotent, so re-running it never perturbs served state).
-    prof = DeviceProfiler()
-    kernel_best = prof.profile_many(
-        kernel_ops.profiling_targets(), rounds=4, warmup=1)
-    for kname in sorted(kernel_best):
-        rec = next(r for r in prof.records if r["name"] == kname)
-        report(f"obs_profile_{kname}", kernel_best[kname],
-               f"best-of-4 interleaved, measured={rec['measured']}",
-               op="obs_profile", measured=rec["measured"])
-
-    server = servers[False]
-    for req in burst(5000, n=4):
-        server.submit(req)
-    for _ in range(3):
-        server.step()
-    dec_us = server.profile_decode(prof, iters=4, warmup=1)
-    server.run_until_drained()
-    server.finished.clear()
-    if dec_us is not None:
-        rec = next(r for r in prof.records
-                   if r["name"] == "paged_decode_step")
-        report("obs_profile_decode_step", dec_us,
-               f"fused tick, live={rec.get('live')}, "
-               f"measured={rec['measured']}",
-               op="obs_profile", measured=rec["measured"],
-               live=rec.get("live"), table_width=rec.get("table_width"))
-
     # ---- cost-model feedback: measured transfer spans -> refit ------------ #
     if jax.device_count() < 2:
         print("obs cost-model rows skipped: needs >= 2 host devices")
@@ -1029,42 +991,17 @@ def obs_sections(report) -> None:
            unit="pct", op="obs_cost", model_error=round(err0, 4),
            alpha_us=cost0.alpha_us, beta_us_per_kib=cost0.beta_us_per_kib)
 
-    # γ measurement: time the receiver epilogue (installing a landed
-    # segment into its resident buffer) ALONE at the same payload
-    # sizes.  On the live path that store overlaps the wire, so its
-    # cost hides inside the fitted end-to-end slope; measured
-    # standalone, its per-KiB slope lets fit_from_trace split the
-    # slope into wire β + epilogue γ without moving hop_us — the
-    # software stand-in for ACCL+'s per-engine hardware counters.
-    def make_install(nbytes):
-        n_el = nbytes // 4
-        dst = jnp.zeros((n_el,), jnp.float32)
-        src = jnp.ones((n_el,), jnp.float32)
-        install = jax.jit(
-            lambda d, s: jax.lax.dynamic_update_slice(d, s, (0,)))
-        return lambda: install(dst, src)
-
-    epi = prof.profile_epilogue(make_install, sizes,
-                                name="epilogue_install", iters=6, warmup=2)
-    fit, note = core_sched.try_fit_from_trace(spans, epilogue_spans=epi)
+    fit, note = core_sched.try_fit_from_trace(spans)
     if fit is None:
         print(f"obs_cost_refit_err skipped: {note}")
         return
     err1 = fit.model_error(spans)
-    gamma_meas = core_sched.EngineCost.fit_gamma_from_trace(epi)
     report("obs_cost_refit_err", err1 * 100,
-           f"fit α={fit.alpha_us:.1f}us β={fit.beta_us_per_kib:.3f} "
-           f"γ={fit.gamma_us_per_kib:.3f}us/KiB",
+           f"fit α={fit.alpha_us:.1f}us β={fit.beta_us_per_kib:.3f}",
            unit="pct", op="obs_cost", model_error=round(err1, 4),
            alpha_us=round(fit.alpha_us, 2),
            beta_us_per_kib=round(fit.beta_us_per_kib, 4),
-           gamma_us_per_kib=round(fit.gamma_us_per_kib, 4),
            n_spans=len(spans), note=note)
-    report("obs_cost_gamma", fit.gamma_us_per_kib,
-           f"epilogue slope {gamma_meas:.3f}us/KiB "
-           f"(measured={epi[0]['measured']}, capped at wire β)",
-           unit="us_per_kib", op="obs_cost", measured=epi[0]["measured"],
-           epilogue_slope_us_per_kib=round(gamma_meas, 4))
 
 
 if __name__ == "__main__":

@@ -130,10 +130,9 @@ class EngineCost:
     def fit_gamma_from_trace(cls, spans: Iterable) -> float:
         """Fit γ (receiver-epilogue us/KiB) from *measured epilogue*
         spans — the install/accumulate program timed alone, at several
-        payload sizes (``obs.profile`` records these).  End-to-end
-        transfer walls cannot separate γ from β (the epilogue overlaps
-        the wire by design); a directly timed epilogue can: its per-KiB
-        slope IS γ.  The per-call dispatch overhead lands in the
+        payload sizes.  End-to-end transfer walls cannot separate γ from
+        β (the epilogue overlaps the wire by design); a directly timed
+        epilogue can: its per-KiB slope IS γ.  The per-call dispatch overhead lands in the
         intercept and is discarded."""
         pts = cls._points(spans)
         _, slope = cls._line_fit(pts, "fit_gamma_from_trace")

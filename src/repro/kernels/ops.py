@@ -32,7 +32,6 @@ __all__ = [
     "selective_scan",
     "gated_linear_scan",
     "aligned",
-    "profiling_targets",
 ]
 
 
@@ -146,57 +145,3 @@ def gated_linear_scan(
     if impl == "chunked":
         return ref.gated_linear_scan_chunked(a, b, chunk=block_s * 2)
     return ref.gated_linear_scan(a, b)
-
-
-def profiling_targets(
-    *,
-    batch: int = 4,
-    heads: int = 4,
-    kv_heads: int = 2,
-    head_dim: int = 64,
-    n_pages: int = 16,
-    page_tokens: int = 8,
-    seed: int = 0,
-):
-    """Named, jitted paged-attention closures over one synthetic decode
-    shape — the targets :meth:`repro.obs.profile.DeviceProfiler.profile_many`
-    interleaves to time the serving hot kernel against its oracle.
-
-    Inputs are built once (device-resident after the first call) so each
-    closure times *only* the kernel dispatch + execution; shapes follow
-    the paged pool layout: q ``(B, Hq, D)`` against ``(P, T, Hkv, D)``
-    physical pages through a ``(B, NP)`` table.  Returns a list of
-    ``(name, fn, tags)`` tuples.
-    """
-    rng = jax.random.PRNGKey(seed)
-    kq, kk, kv_, kl = jax.random.split(rng, 4)
-    q = jax.random.normal(kq, (batch, heads, head_dim), jnp.float32)
-    k_pages = jax.random.normal(
-        kk, (n_pages, page_tokens, kv_heads, head_dim), jnp.float32)
-    v_pages = jax.random.normal(
-        kv_, (n_pages, page_tokens, kv_heads, head_dim), jnp.float32)
-    per_req = n_pages // batch
-    table = jnp.arange(batch * per_req, dtype=jnp.int32).reshape(
-        batch, per_req) % n_pages
-    lengths = jax.random.randint(
-        kl, (batch,), page_tokens, per_req * page_tokens + 1
-    ).astype(jnp.int32)
-
-    def make(impl):
-        @jax.jit
-        def run():
-            return paged_attention(
-                q, k_pages, v_pages, table, lengths,
-                impl=impl,
-            )
-        return run
-
-    tags = {
-        "batch": batch, "heads": heads, "kv_heads": kv_heads,
-        "head_dim": head_dim, "n_pages": n_pages,
-        "page_tokens": page_tokens,
-    }
-    return [
-        ("paged_attention_pallas", make("pallas"), {**tags, "impl": "pallas"}),
-        ("paged_attention_ref", make("ref"), {**tags, "impl": "ref"}),
-    ]

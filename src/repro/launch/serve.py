@@ -38,6 +38,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from repro.obs import trace as obs_trace
+from repro.obs.metrics import Registry
 
 
 def _paged_decode_fn(model, ctx, layout):
@@ -351,16 +352,19 @@ class Server:
     # ------------------------------------------------------------------ #
     def step(self) -> int:
         """One scheduler tick: admit, decode one token for all rows.
-        Subclasses override :meth:`_step`; this wrapper is the single
-        place every server's tick gets its ``decode_step`` span."""
-        tr = obs_trace.active()
-        if not tr.enabled:
-            return self._step()
-        with tr.span("decode_step", cat="decode",
-                     rank=self.trace_rank) as sp:
+        Subclasses override :meth:`_step` and :meth:`_after_step`; this
+        wrapper is the single place every server's tick gets its
+        ``serve.tick`` span."""
+        with obs_trace.span("serve.tick", cat="decode",
+                            rank=self.trace_rank) as sp:
             n = self._step()
-            sp.args["live"] = n
-            return n
+            self._after_step()
+            if sp is not None:
+                sp.args["live"] = n
+        return n
+
+    def _after_step(self) -> None:
+        """Called at the end of every tick, inside its span."""
 
     def _step(self) -> int:
         self._admit()
@@ -428,6 +432,18 @@ class PagedServer(Server):
 
     Token parity with :class:`Server` — pressured or not — is the
     correctness bar (asserted in the smoke demo and tests).
+
+    On the paged path each part of a tick's host work runs in a
+    ``serve.*`` span (:func:`repro.obs.trace.span`: in the profiler's
+    trace, beside the device's operations), and ``metrics`` counts what
+    the tick moved, from host-known shapes: ``admissions`` (with
+    recompute resumes), ``prefill_tokens``, ``prefill_cached_tokens``
+    (prompt tokens on shared prefix pages), ``ticks`` and
+    ``decode_rows`` (decode steps, and live rows summed over them),
+    ``d2h_bytes`` (flattened prompt caches and pool syncs to the host),
+    ``h2d_bytes`` (patch programs' operands and pool re-uploads),
+    ``host_syncs``, and the gauge ``pool_pages_peak`` (most pool pages
+    in use at a decode step).
     """
 
     def __init__(self, model, ctx, params, batch_size: int, cache_len: int,
@@ -488,38 +504,47 @@ class PagedServer(Server):
         self._read = _pool_read_fn(self.layout)
         self._pending_rows: Dict[int, np.ndarray] = {}
         self._pending_copies: List[tuple] = []
+        self.metrics = Registry()
+        for name in ("admissions", "prefill_tokens", "prefill_cached_tokens",
+                     "ticks", "decode_rows", "d2h_bytes", "h2d_bytes",
+                     "host_syncs"):
+            self.metrics.counter(name)
+        self.metrics.gauge("pool_pages_peak")
 
     def _apply_pending(self) -> None:
         """Flush queued page writes/copies into the device-resident pool.
         Writes flush (in chunks) before any copy: a COW split may source
         a page admitted this same tick."""
-        jnp = self.jnp
-        P = self.store.state.n_pages  # scratch index pads the chunks
-        elems = self.layout.page_elems
-        rows = list(self._pending_rows.items())
-        copies = list(self._pending_copies)
-        self._pending_rows.clear()
-        self._pending_copies.clear()
-        pad_idx = np.full((_PATCH_CHUNK,), P, np.int32)
-        pad_rows = np.zeros((_PATCH_CHUNK, elems), np.float32)
-        while rows:
-            chunk, rows = rows[:_PATCH_CHUNK], rows[_PATCH_CHUNK:]
-            wd, wr = pad_idx.copy(), pad_rows.copy()
-            for j, (pg, row) in enumerate(chunk):
-                wd[j], wr[j] = pg, row
-            self._dev_views = self._patch(
-                self._dev_views, jnp.asarray(wd), jnp.asarray(wr),
-                jnp.asarray(pad_idx), jnp.asarray(pad_idx),
-            )
-        while copies:
-            chunk, copies = copies[:_PATCH_CHUNK], copies[_PATCH_CHUNK:]
-            cs, cd = pad_idx.copy(), pad_idx.copy()
-            for j, (src, dst) in enumerate(chunk):
-                cs[j], cd[j] = src, dst
-            self._dev_views = self._patch(
-                self._dev_views, pad_idx, pad_rows,
-                jnp.asarray(cs), jnp.asarray(cd),
-            )
+        with obs_trace.span("serve.patch"):
+            jnp = self.jnp
+            P = self.store.state.n_pages  # scratch index pads the chunks
+            elems = self.layout.page_elems
+            rows = list(self._pending_rows.items())
+            copies = list(self._pending_copies)
+            self._pending_rows.clear()
+            self._pending_copies.clear()
+            pad_idx = np.full((_PATCH_CHUNK,), P, np.int32)
+            pad_rows = np.zeros((_PATCH_CHUNK, elems), np.float32)
+            h2d = self.metrics.counter("h2d_bytes")
+
+            def send(wd, wr, cs, cd):
+                ops = (wd, wr, cs, cd)
+                self._dev_views = self._patch(
+                    self._dev_views, *(jnp.asarray(x) for x in ops))
+                h2d.inc(sum(x.nbytes for x in ops))
+
+            while rows:
+                chunk, rows = rows[:_PATCH_CHUNK], rows[_PATCH_CHUNK:]
+                wd, wr = pad_idx.copy(), pad_rows.copy()
+                for j, (pg, row) in enumerate(chunk):
+                    wd[j], wr[j] = pg, row
+                send(wd, wr, pad_idx, pad_idx)
+            while copies:
+                chunk, copies = copies[:_PATCH_CHUNK], copies[_PATCH_CHUNK:]
+                cs, cd = pad_idx.copy(), pad_idx.copy()
+                for j, (src, dst) in enumerate(chunk):
+                    cs[j], cd[j] = src, dst
+                send(pad_idx, pad_rows, cs, cd)
 
     def _sync_host(self) -> None:
         """Land the device-resident pool back in the host mirror before
@@ -528,10 +553,13 @@ class PagedServer(Server):
         flush to the device first so the download is complete.  The
         device copy is dropped; the next decode step re-uploads the
         mutated mirror."""
-        if self._dev_views is not None:
+        if self._dev_views is None:
+            return
+        with obs_trace.span("serve.sync_host"):
             if self._pending_rows or self._pending_copies:
                 self._apply_pending()
             P = self.store.state.n_pages
+            d2h = self.metrics.counter("d2h_bytes")
             for lo in range(0, P, _READ_CHUNK):
                 idx = np.minimum(
                     np.arange(lo, lo + _READ_CHUNK, dtype=np.int32), P
@@ -539,8 +567,10 @@ class PagedServer(Server):
                 rows = np.asarray(
                     self._read(self._dev_views, self.jnp.asarray(idx))
                 )
+                d2h.inc(rows.nbytes)
                 self.store.mem[lo : lo + _READ_CHUNK] = rows[: P - lo]
             self._dev_views = None
+            self.metrics.counter("host_syncs").inc()
 
     def _live_pages(self) -> np.ndarray:
         """Pages some request references; free pages hold nothing that
@@ -661,10 +691,40 @@ class PagedServer(Server):
         self.last_token[slot, 0] = int(last_token)
 
     def _prefill_pages(self, req: Request):
-        toks = self.jnp.asarray(req.prompt, self.jnp.int32)[None]
-        logits, caches_one = self._prefill_one(self.params, {"inputs": toks})
-        tok = int(np.argmax(np.asarray(logits)[0]))
-        return tok, np.asarray(self.layout.flatten(caches_one)), caches_one
+        with obs_trace.span("serve.prefill"):
+            toks = self.jnp.asarray(req.prompt, self.jnp.int32)[None]
+            logits, caches_one = self._prefill_one(
+                self.params, {"inputs": toks})
+            tok = int(np.argmax(np.asarray(logits)[0]))
+        with obs_trace.span("serve.flatten"):
+            pages = np.asarray(self.layout.flatten(caches_one))
+        self.metrics.counter("d2h_bytes").inc(pages.nbytes)
+        return tok, pages, caches_one
+
+    def _admit_prompt(self, req: Request) -> int:
+        """Prefill ``req``'s prompt into the pool (its leading full pages
+        shared with resident prefixes, the tail left to lazy growth) and
+        commit its page table; returns the first token.  With the pool
+        on the device, only the fresh prompt pages queue, as patches,
+        instead of the whole pool round-tripping through the host."""
+        with obs_trace.span("serve.admit"):
+            tok, pages, _ = self._prefill_pages(req)
+            with obs_trace.span("serve.pool_write"):
+                plan = self.store.plan_admit(req.prompt, lazy=True)
+                self.store.write_pages(plan, pages)
+                self.store.commit(req.rid, plan)
+                if self._dev_views is not None:
+                    for page_id, is_fresh in zip(plan.table, plan.fresh):
+                        if is_fresh:
+                            self._pending_rows[page_id] = self.store.mem[
+                                page_id
+                            ].copy()
+        m = self.metrics
+        m.counter("admissions").inc()
+        m.counter("prefill_tokens").inc(len(req.prompt))
+        m.counter("prefill_cached_tokens").inc(
+            len(plan.shared) * self.layout.page_tokens)
+        return tok
 
     def _resume(self, rid: int, slot: int) -> bool:
         st = self._preempted[rid]
@@ -683,10 +743,7 @@ class PagedServer(Server):
         else:  # recompute: re-prefill the prompt, replay the generation
             if self.store.n_free < self.layout.pages_for(len(req.prompt)):
                 return False
-            tok, pages, _ = self._prefill_pages(req)
-            plan = self.store.plan_admit(req.prompt, lazy=True)
-            self.store.write_pages(plan, pages)
-            self.store.commit(rid, plan)
+            self._admit_prompt(req)
             self._bind_row(req, slot, len(req.prompt), req.out[0])
             self.start_replay(slot, req.out[1:])
         del self._preempted[rid]
@@ -716,19 +773,7 @@ class PagedServer(Server):
             ):
                 continue
             self.queue.remove(req)
-            tok, pages, _ = self._prefill_pages(req)
-            plan = self.store.plan_admit(req.prompt, lazy=True)
-            self.store.write_pages(plan, pages)
-            self.store.commit(req.rid, plan)
-            if self._dev_views is not None:
-                # the pool stays device-resident across admissions: queue
-                # only the fresh prompt pages as patches instead of
-                # round-tripping the whole pool through the host mirror
-                for page_id, is_fresh in zip(plan.table, plan.fresh):
-                    if is_fresh:
-                        self._pending_rows[page_id] = self.store.mem[
-                            page_id
-                        ].copy()
+            tok = self._admit_prompt(req)
             if not req.out:
                 req.out.append(tok)
             self._bind_row(req, slot, len(req.prompt), req.out[0])
@@ -772,32 +817,33 @@ class PagedServer(Server):
         # once pages free up.
         from repro.serving.pool import UNMATERIALIZED
 
-        for i in list(live):
-            req = self.active[i]
-            if req is None:
-                continue  # already evicted by an earlier row's make_room
-            need = self._write_need(req.rid, int(self.positions[i]))
-            if need and self.store.n_free < need:
-                if not self._make_room(need, req.rid, strict=False):
-                    self._preempt(req.rid)
-                    continue
-            pos = int(self.positions[i])
-            if need and self._dev_views is not None:
-                # materialisation / COW split mutates page payloads: mirror
-                # the host-side bookkeeping write as a device patch rather
-                # than syncing the whole pool down and back up
-                before = self.store.tables[req.rid][
-                    pos // self.layout.page_tokens
-                ]
-                dst = self.store.prepare_write(req.rid, pos)
-                if before == UNMATERIALIZED:
-                    self._pending_rows[dst] = np.asarray(
-                        self.layout.empty_page_row()
-                    )
-                elif dst != before:  # COW split: clone the shared payload
-                    self._pending_copies.append((int(before), int(dst)))
-            else:
-                self.store.prepare_write(req.rid, pos)
+        with obs_trace.span("serve.grow"):
+            for i in list(live):
+                req = self.active[i]
+                if req is None:
+                    continue  # already evicted by an earlier row's make_room
+                need = self._write_need(req.rid, int(self.positions[i]))
+                if need and self.store.n_free < need:
+                    if not self._make_room(need, req.rid, strict=False):
+                        self._preempt(req.rid)
+                        continue
+                pos = int(self.positions[i])
+                if need and self._dev_views is not None:
+                    # materialisation / COW split mutates page payloads: mirror
+                    # the host-side bookkeeping write as a device patch rather
+                    # than syncing the whole pool down and back up
+                    before = self.store.tables[req.rid][
+                        pos // self.layout.page_tokens
+                    ]
+                    dst = self.store.prepare_write(req.rid, pos)
+                    if before == UNMATERIALIZED:
+                        self._pending_rows[dst] = np.asarray(
+                            self.layout.empty_page_row()
+                        )
+                    elif dst != before:  # COW split: clone the shared payload
+                        self._pending_copies.append((int(before), int(dst)))
+                else:
+                    self.store.prepare_write(req.rid, pos)
         live = [i for i, r in enumerate(self.active) if r is not None]
         if not live:
             return 0
@@ -808,24 +854,30 @@ class PagedServer(Server):
         # growth step, never thrashes): paged attention then reads ONLY
         # pages any request can occupy, instead of paying the full
         # cache_len width the dense rows are stuck with.
-        P = self.store.state.n_pages
-        T = self.layout.page_tokens
-        need = max(int(self.positions[i]) // T + 1 for i in live)
-        need = min(self.layout.n_pages, -(-need // 4) * 4)  # 4-page buckets
-        self._table_width = max(self._table_width, need)
-        tables = np.full((self.B, self._table_width), P, np.int32)
-        for i in live:
-            row = self.store.device_table(self.active[i].rid, absent=P)
-            tables[i] = row[: self._table_width]
+        with obs_trace.span("serve.tables"):
+            P = self.store.state.n_pages
+            T = self.layout.page_tokens
+            need = max(int(self.positions[i]) // T + 1 for i in live)
+            need = min(self.layout.n_pages, -(-need // 4) * 4)  # 4-page buckets
+            self._table_width = max(self._table_width, need)
+            tables = np.full((self.B, self._table_width), P, np.int32)
+            for i in live:
+                row = self.store.device_table(self.active[i].rid, absent=P)
+                tables[i] = row[: self._table_width]
+        m = self.metrics
+        m.counter("ticks").inc()
+        m.counter("decode_rows").inc(len(live))
+        peak = m.gauge("pool_pages_peak")
+        peak.set(max(peak.get(), P - self.store.n_free))
         logits = self._decode_via_tables(tables)
-        for i in live:
-            if i not in self.replaying:  # replays are not new generation
-                self.scheduler.on_step(self.active[i].rid)
-        self._advance(live, logits)
+        with obs_trace.span("serve.advance"):
+            for i in live:
+                if i not in self.replaying:  # replays are not new generation
+                    self.scheduler.on_step(self.active[i].rid)
+            self._advance(live, logits)
         return len(live)
 
-    def step(self) -> int:
-        n = super().step()
+    def _after_step(self) -> None:
         if self.health is not None:
             self._tick_no += 1
             self.health.tick(
@@ -835,34 +887,6 @@ class PagedServer(Server):
                     for r in self.active if r is not None
                 },
             )
-        return n
-
-    def profile_decode(self, profiler, iters: int = 6,
-                       warmup: int = 2) -> Optional[float]:
-        """Offline device-timing of the fused paged decode step over the
-        server's *current* page tables (re-execution is idempotent: the
-        step rewrites the same K/V slots from the same inputs, and the
-        sampled token is discarded).  Never called on the serving path —
-        benchmarks drive it between bursts.  Returns the best wall/device
-        microseconds, or None when no rows are live."""
-        live = [i for i, r in enumerate(self.active) if r is not None]
-        if not live or not self.paged_decode:
-            return None
-        P = self.store.state.n_pages
-        T = self.layout.page_tokens
-        need = max(int(self.positions[i]) // T + 1 for i in live)
-        need = min(self.layout.n_pages, -(-need // 4) * 4)
-        width = max(self._table_width, need)
-        tables = np.full((self.B, width), P, np.int32)
-        for i in live:
-            tables[i] = self.store.device_table(
-                self.active[i].rid, absent=P)[:width]
-        return profiler.profile(
-            "paged_decode_step",
-            lambda: self._decode_via_tables(tables),
-            iters=iters, warmup=warmup,
-            live=len(live), table_width=width,
-        )
 
     def _decode_via_tables(self, tables: np.ndarray) -> np.ndarray:
         """Upload the pool when host-resident, flush queued page patches,
@@ -873,30 +897,33 @@ class PagedServer(Server):
         if self._dev_views is None:  # (re-)upload the mutated host mirror
             # empty pages on the device, then the live ones as patches:
             # the pool crosses in page chunks, never as one float32 carrier
-            P = self.store.state.n_pages
-            empty = self.layout.decode_views(
-                self.jnp.asarray(self.layout.empty_page_row()[None])
-            )
-            self._dev_views = self.jax.tree_util.tree_map(
-                lambda e: self.jnp.broadcast_to(
-                    e, e.shape[:1] + (P + 1,) + e.shape[2:]
-                ),
-                empty,
-            )
-            for page in self._live_pages():
-                self._pending_rows.setdefault(
-                    int(page), self.store.mem[page].copy()
+            with obs_trace.span("serve.upload"):
+                P = self.store.state.n_pages
+                row = self.layout.empty_page_row()[None]
+                empty = self.layout.decode_views(self.jnp.asarray(row))
+                self.metrics.counter("h2d_bytes").inc(row.nbytes)
+                self._dev_views = self.jax.tree_util.tree_map(
+                    lambda e: self.jnp.broadcast_to(
+                        e, e.shape[:1] + (P + 1,) + e.shape[2:]
+                    ),
+                    empty,
                 )
+                for page in self._live_pages():
+                    self._pending_rows.setdefault(
+                        int(page), self.store.mem[page].copy()
+                    )
         if self._pending_rows or self._pending_copies:
             self._apply_pending()
-        logits, self._dev_views = self._decode_paged(
-            self.params,
-            self.jnp.asarray(self.last_token),
-            self.jnp.asarray(self.positions),
-            self._dev_views,
-            self.jnp.asarray(tables),
-        )
-        return np.asarray(logits)
+        with obs_trace.span("serve.decode"):
+            logits, self._dev_views = self._decode_paged(
+                self.params,
+                self.jnp.asarray(self.last_token),
+                self.jnp.asarray(self.positions),
+                self._dev_views,
+                self.jnp.asarray(tables),
+            )
+        with obs_trace.span("serve.logits"):
+            return np.asarray(logits)
 
     # ------------------------------------------------------------------ #
     def _post_decode(self, live: List[int], written: Dict[int, int]) -> None:

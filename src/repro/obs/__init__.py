@@ -10,15 +10,13 @@ hardware performance counters.  This package is our software analogue:
   ``reset()`` that only clears counters).
 - :mod:`repro.obs.trace` — a per-rank span/event tracer clocked on the
   SPMD tick counter.  Spans are host-side (around initiation and sync,
-  never inside compiled code), ring-buffered, and free when disabled:
-  every instrumentation site is guarded by one attribute check on a
-  no-op recorder.
+  never inside compiled code) and ring-buffered; scoped spans also
+  land in the JAX profiler's trace, beside the device's operations.
+  Disabled, a scoped span costs about a microsecond and every other
+  site is guarded by one attribute check on a no-op recorder.
 - :mod:`repro.obs.export` — merges per-rank streams on the tick clock
   into Chrome-trace JSON (load in ``chrome://tracing`` / Perfetto) and
   dumps a flight-recorder ring of the last N ticks on rank death.
-- :mod:`repro.obs.profile` — device-time kernel profiling by (honest,
-  labelled) timed re-execution; measures the receiver epilogue alone so
-  ``EngineCost`` finally fits γ from data.
 - :mod:`repro.obs.attrib` — per-request critical-path attribution over
   the recorded lifecycle instants; ``why_slow(rid)`` names the dominant
   segment and the convoying co-residents.
@@ -29,11 +27,10 @@ hardware performance counters.  This package is our software analogue:
 Nothing here imports the rest of ``repro`` — core and serving layers
 import ``obs``, never the other way around.
 """
-from repro.obs import attrib, export, health, metrics, profile, trace
+from repro.obs import attrib, export, health, metrics, trace
 from repro.obs.attrib import Breakdown, attribute, why_slow
 from repro.obs.health import HealthMonitor
 from repro.obs.metrics import Counter, Gauge, Histogram, Registry
-from repro.obs.profile import DeviceProfiler
 from repro.obs.trace import (
     NullTracer,
     Span,
@@ -41,12 +38,12 @@ from repro.obs.trace import (
     active,
     disable,
     enable,
+    span,
 )
 
 __all__ = [
     "Breakdown",
     "Counter",
-    "DeviceProfiler",
     "Gauge",
     "HealthMonitor",
     "Histogram",
@@ -62,7 +59,7 @@ __all__ = [
     "export",
     "health",
     "metrics",
-    "profile",
+    "span",
     "trace",
     "why_slow",
 ]

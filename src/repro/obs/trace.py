@@ -1,4 +1,5 @@
-"""Per-rank span/event tracing clocked on the SPMD tick counter.
+"""Per-rank span/event tracing clocked on the SPMD tick counter, and
+host spans on the JAX profiler's clock.
 
 GASNet's split-phase operations are invisible between initiation and
 sync — ``GASNET_TRACE`` exists because a hung ``gasnet_put_nb`` tells
@@ -11,16 +12,27 @@ JAX:
   ``sync_all``) — never inside traced/compiled code.  Handles are
   trace-time Python objects, so a split-phase span simply rides the
   handle from initiation to sync.
-- **Zero-cost when disabled.**  ``active()`` returns a module-level
-  no-op recorder unless tracing was enabled; every instrumentation
-  site in the hot path guards on one attribute check
+- **Almost free when disabled.**  ``active()`` returns a module-level
+  no-op recorder unless tracing was enabled; instrumentation sites that
+  record more than a scoped span guard on one attribute check
   (``tr = trace.active(); if tr.enabled: ...``).
 
-Timestamps are dual: the **tick clock** (``set_tick`` + a per-tick
-sequence number) is deterministic and is what the export merges ranks
-on; the wall clock (``perf_counter``) rides along in every event for
-real durations (e.g. ``EngineCost.fit_from_trace``).  Span ids are a
-plain counter — deterministic across replays of the same schedule.
+Scoped spans (``span(...)``, on the active recorder or the module-level
+:func:`span`) have two sinks.  Each enters a
+``jax.profiler.TraceAnnotation`` of its name, so that while a profiler
+session runs it lands on the ``/host`` plane of the profiler's trace,
+on the same clock as the device planes: a device idle gap can be put
+down to the innermost host span around it.  With no session that costs
+about a microsecond.  When a :class:`Tracer` is enabled the span is
+also recorded in its ring.  Split-phase spans and instants go to the
+ring only.
+
+Timestamps in the ring are dual: the **tick clock** (``set_tick`` + a
+per-tick sequence number) is deterministic and is what the export
+merges ranks on; the wall clock (``perf_counter``) rides along in every
+event for real durations (e.g. ``EngineCost.fit_from_trace``).  Span
+ids are a plain counter — deterministic across replays of the same
+schedule.
 
 Events live in a bounded ring (``collections.deque``), which is what
 makes the flight recorder free: the last-N-ticks dump on rank death is
@@ -32,6 +44,8 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.metrics import Registry
 
 __all__ = [
@@ -41,6 +55,7 @@ __all__ = [
     "active",
     "disable",
     "enable",
+    "span",
 ]
 
 
@@ -83,26 +98,22 @@ class Span:
         )
 
 
-class _NoopCtx:
-    """Reusable no-op context manager (``NullTracer.span``)."""
+class _Annotation(TraceAnnotation):
+    """A profiler host span that records nothing else
+    (``NullTracer.span``): a ``TraceMe`` while a profiler session runs,
+    a near no-op otherwise.  Entering it yields None, as the disabled
+    recorder's spans always have."""
 
     __slots__ = ()
 
     def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_CTX = _NoopCtx()
+        TraceAnnotation.__enter__(self)
 
 
 class NullTracer:
     """The disabled recorder: every method is a no-op, ``enabled`` is
-    False.  Instrumentation sites check ``enabled`` once and skip; the
-    per-tick sites that use ``span(...)`` contexts get a shared no-op
-    context object."""
+    False, except that ``span(...)`` still writes a profiler host span.
+    Instrumentation sites check ``enabled`` once and skip the rest."""
 
     enabled = False
     __slots__ = ()
@@ -129,23 +140,27 @@ class NullTracer:
         pass
 
     def span(self, name, cat="span", rank=None, **args):
-        return _NOOP_CTX
+        return _Annotation(name)
 
 
 class _SpanCtx:
-    """Context manager pairing ``begin``/``end`` for scoped spans."""
+    """Context manager pairing ``begin``/``end`` for scoped spans, inside
+    a profiler host span of the same name."""
 
-    __slots__ = ("_tr", "_span")
+    __slots__ = ("_tr", "_span", "_ann")
 
     def __init__(self, tr: "Tracer", span: Span):
         self._tr = tr
         self._span = span
+        self._ann = TraceAnnotation(span.name)
 
     def __enter__(self) -> Span:
+        self._ann.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> bool:
         self._tr.end(self._span)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -238,6 +253,8 @@ class Tracer:
 
     def span(self, name: str, cat: str = "span",
              rank: Optional[int] = None, **args) -> _SpanCtx:
+        """A scoped span, recorded in the ring and written to the
+        profiler's trace (see the module docstring)."""
         return _SpanCtx(self, self.begin(name, cat, rank=rank, **args))
 
     # ---------------------------------------------------------------- #
@@ -325,6 +342,14 @@ def active() -> Any:
     :class:`NullTracer` otherwise.  Hot paths call this then guard on
     ``.enabled``."""
     return _ACTIVE
+
+
+def span(name: str, cat: str = "span", rank: Optional[int] = None,
+         **args):
+    """``active().span(...)``: a scoped host span in the profiler's
+    trace, and in the ring when a :class:`Tracer` is enabled.  Entering
+    it yields the ring's :class:`Span`, or None when tracing is off."""
+    return _ACTIVE.span(name, cat, rank, **args)
 
 
 def enable(tracer: Optional[Tracer] = None, **kw) -> Tracer:
