@@ -97,11 +97,11 @@ def recording_server(*args, script=None, **kw):
             self.own_first = {}  # rid -> the token its own prefill chose
 
         def _prefill_pages(self, req):
-            tok, pages, caches = super()._prefill_pages(req)
+            tok, caches = super()._prefill_pages(req)
             self.own_first[req.rid] = tok
             if script is not None:
                 tok = script[req.rid][0]
-            return tok, pages, caches
+            return tok, caches
 
         def _decode_via_tables(self, tables):
             logits = super()._decode_via_tables(tables)

@@ -73,6 +73,7 @@ def paged_attention(
     v_pages: jax.Array,
     page_table: jax.Array,
     lengths: jax.Array,
+    layer: Optional[jax.Array] = None,
     *,
     scale: Optional[float] = None,
     impl: str = "ref",
@@ -81,7 +82,8 @@ def paged_attention(
 ) -> jax.Array:
     """Decode attention through a page table (the paged KV pool's compute
     side): q (B, Hq, D) against (P, T, Hkv, D) physical pages addressed by
-    page_table (B, NP), masked at lengths (B,).
+    page_table (B, NP), masked at lengths (B,).  A 5-D pool
+    (L, P, T, Hkv, D) is read at ``layer``, without a slice of it.
 
     ``pages_per_block``/``block_b`` tune the pallas kernel's DMA blocking
     (pages streamed per grid step / requests sharing a burst) — pure perf
@@ -89,12 +91,12 @@ def paged_attention(
     """
     if impl == "pallas":
         return _pa.paged_attention(
-            q, k_pages, v_pages, page_table, lengths,
+            q, k_pages, v_pages, page_table, lengths, layer,
             scale=scale,
             pages_per_block=pages_per_block, block_b=block_b,
         )
     return ref.paged_attention(
-        q, k_pages, v_pages, page_table, lengths, scale=scale
+        q, k_pages, v_pages, page_table, lengths, layer, scale=scale
     )
 
 

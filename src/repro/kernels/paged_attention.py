@@ -27,6 +27,11 @@ granularity):
   behind the online-softmax work exactly like the split-phase GASNet
   ops hide behind the decode step.
 
+The pool may hold every layer, ``(L, P, T, Hkv, D)``: a scalar-prefetched
+layer index picks the layer inside the DMAs, so a decode step that
+carries the whole pool through its layer loop hands it over as it is and
+no per-layer slice of it is ever made.
+
 All heads of a request are scored in one matmul: the page is viewed as
 ``(T * Hkv, D)`` rows, every query head is scored against every row, and
 rows of other KV heads are masked like positions past the length.  The
@@ -73,9 +78,10 @@ DEFAULT_PAGES_PER_BLOCK = 4
 def _pa_kernel(
     table_ref,  # scalar prefetch: (B * NP,) physical page ids
     len_ref,  # scalar prefetch: (B,) live lengths
+    layer_ref,  # scalar prefetch: (1,) the layer of the pool to read
     q_ref,  # (BLOCK_B, Hq, D)
-    k_hbm,  # (P, T, Hkv, D) — full pool, memory_space=ANY
-    v_hbm,  # (P, T, Hkv, D)
+    k_hbm,  # (L, P, T, Hkv, D) — full pool, memory_space=ANY
+    v_hbm,  # (L, P, T, Hkv, D)
     o_ref,  # (BLOCK_B, Hq, D)
     k_buf,  # VMEM (2, BLOCK_B, PPB, T, Hkv, D) double-buffered page blocks
     v_buf,
@@ -96,6 +102,7 @@ def _pa_kernel(
     npb = pl.num_programs(1)
     T = page_tokens
     Hkv = k_buf.shape[4]
+    layer = layer_ref[0]
 
     def issue(slot, blk):
         """One DMA burst: every (request, page) of one page block."""
@@ -106,11 +113,11 @@ def _pa_kernel(
                 gp = jnp.minimum(blk * pages_per_block + j, n_pages - 1)
                 page = table_ref[gb * n_pages + gp]
                 pltpu.make_async_copy(
-                    k_hbm.at[page], k_buf.at[slot, i, j],
+                    k_hbm.at[layer, page], k_buf.at[slot, i, j],
                     sems.at[0, slot, i, j],
                 ).start()
                 pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[slot, i, j],
+                    v_hbm.at[layer, page], v_buf.at[slot, i, j],
                     sems.at[1, slot, i, j],
                 ).start()
 
@@ -118,10 +125,12 @@ def _pa_kernel(
         for i in range(block_b):
             for j in range(pages_per_block):
                 pltpu.make_async_copy(
-                    k_hbm.at[0], k_buf.at[slot, i, j], sems.at[0, slot, i, j],
+                    k_hbm.at[0, 0], k_buf.at[slot, i, j],
+                    sems.at[0, slot, i, j],
                 ).wait()
                 pltpu.make_async_copy(
-                    v_hbm.at[0], v_buf.at[slot, i, j], sems.at[1, slot, i, j],
+                    v_hbm.at[0, 0], v_buf.at[slot, i, j],
+                    sems.at[1, slot, i, j],
                 ).wait()
 
     @pl.when(pb == 0)
@@ -201,6 +210,7 @@ def paged_attention(
     v_pages: jax.Array,
     page_table: jax.Array,
     lengths: jax.Array,
+    layer: Optional[jax.Array] = None,
     *,
     scale: Optional[float] = None,
     pages_per_block: Optional[int] = None,
@@ -210,11 +220,15 @@ def paged_attention(
 
     Args:
       q: (B, Hq, D) — one query token per request (decode step).
-      k_pages, v_pages: (P, T, Hkv, D) — the physical page pool.
+      k_pages, v_pages: (P, T, Hkv, D) — the physical page pool; or the
+        pools of every layer, (L, P, T, Hkv, D), of which the kernel's
+        DMAs read layer ``layer`` alone (no slice of the pool is made).
       page_table: (B, NP) int32 — physical page id of request b's logical
         page p; entries at or past ``ceil(lengths[b] / T)`` are masked and
         may hold any valid physical id (even pages holding garbage).
       lengths: (B,) int32 — live cache positions per request.
+      layer: int32 scalar, the layer to read of a 5-D pool; given with a
+        5-D pool and only then.
       pages_per_block: physical pages streamed per grid step (default
         ``DEFAULT_PAGES_PER_BLOCK``, clamped to NP).  Perf knob only —
         the output is bit-identical across settings.
@@ -223,8 +237,18 @@ def paged_attention(
     Returns:
       (B, Hq, D) in q.dtype.
     """
+    if k_pages.ndim == 4:
+        if layer is not None:
+            raise ValueError("layer is given only with a 5-D pool")
+        # a pool of one layer: a leading axis of 1 is a free reshape
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    elif k_pages.ndim != 5 or layer is None:
+        raise ValueError(
+            f"k_pages must be (P, T, Hkv, D), or (L, P, T, Hkv, D) with a "
+            f"layer; got {k_pages.shape} with layer {layer!r}"
+        )
     B, Hq, D = q.shape
-    P, T, Hkv, Dk = k_pages.shape
+    _, P, T, Hkv, Dk = k_pages.shape
     if Dk != D:
         raise ValueError(f"head_dim mismatch: q has {D}, pages have {Dk}")
     if k_pages.shape != v_pages.shape:
@@ -267,17 +291,17 @@ def paged_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(Bp // blk_b, npb),
             in_specs=[
                 pl.BlockSpec(
-                    (blk_b, Hq, D), lambda b, p, table, lens: (b, 0, 0)
+                    (blk_b, Hq, D), lambda b, p, table, lens, lyr: (b, 0, 0)
                 ),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
-                (blk_b, Hq, D), lambda b, p, table, lens: (b, 0, 0)
+                (blk_b, Hq, D), lambda b, p, table, lens, lyr: (b, 0, 0)
             ),
             scratch_shapes=[
                 pltpu.VMEM((2, blk_b, ppb, T, Hkv, D), k_pages.dtype),
@@ -294,5 +318,6 @@ def paged_attention(
         ),
         interpret=mode.interpret(),
         name="paged_attention_decode",
-    )(table.reshape(-1), lens, qq, k_pages, v_pages)
+    )(table.reshape(-1), lens, jnp.asarray(layer, jnp.int32).reshape(1),
+      qq, k_pages, v_pages)
     return out[:B]

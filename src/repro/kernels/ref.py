@@ -122,6 +122,7 @@ def paged_attention(
     v_pages: jax.Array,
     page_table: jax.Array,
     lengths: jax.Array,
+    layer: Optional[jax.Array] = None,
     *,
     scale: Optional[float] = None,
 ) -> jax.Array:
@@ -129,22 +130,33 @@ def paged_attention(
 
     q: (B, Hq, D) — one query token per request.
     k_pages / v_pages: (P, T, Hkv, D) — the physical page pool: P pages of
-      T tokens each (the KV pool's carrier blocks, unflattened).
+      T tokens each (the KV pool's carrier blocks, unflattened); or the
+      pools of every layer, (L, P, T, Hkv, D), read at ``layer``.
     page_table: (B, NP) int32 — request b's logical page p lives in
       physical page ``page_table[b, p]``; entries past the live length may
       point anywhere (they are masked).
     lengths: (B,) int32 — number of live cache positions per request.
+    layer: int32 scalar, given with a 5-D pool and only then.
     """
+    if (k_pages.ndim == 5) != (layer is not None):
+        raise ValueError(
+            f"a 5-D pool takes a layer, a 4-D one none: {k_pages.shape}, "
+            f"layer {layer!r}"
+        )
     B, Hq, D = q.shape
-    _, T, Hkv, _ = k_pages.shape
+    T, Hkv = k_pages.shape[-3:-1]
     NP = page_table.shape[1]
     S = NP * T
     group = Hq // Hkv
     if scale is None:
         scale = 1.0 / (D**0.5)
+    if layer is None:
+        kg, vg = k_pages[page_table], v_pages[page_table]
+    else:
+        kg, vg = k_pages[layer, page_table], v_pages[layer, page_table]
     # gather: (B, NP, T, Hkv, D) -> (B, Hkv, S, D)
-    kd = jnp.moveaxis(k_pages[page_table].reshape(B, S, Hkv, D), 1, 2)
-    vd = jnp.moveaxis(v_pages[page_table].reshape(B, S, Hkv, D), 1, 2)
+    kd = jnp.moveaxis(kg.reshape(B, S, Hkv, D), 1, 2)
+    vd = jnp.moveaxis(vg.reshape(B, S, Hkv, D), 1, 2)
     kx = jnp.repeat(kd, group, axis=1)  # (B, Hq, S, D)
     vx = jnp.repeat(vd, group, axis=1)
     s = jnp.einsum(

@@ -76,8 +76,9 @@ def _paged_decode_views_fn(model, ctx, layout):
     resident ON DEVICE in *decode-views* form (the per-layer page-pool
     pytree) across ticks, so a steady-state step runs zero carrier
     repacks — the carrier<->views conversion happens only at host sync
-    points.  The views buffers are donated: the per-layer token scatter
-    updates in place instead of copying every pool."""
+    points.  The views buffers are donated, and the decode step carries
+    them through its layer loop: each layer's token scatter updates the
+    pool in place, with no copy of the pool or of a layer of it."""
     import jax
     import jax.numpy as jnp
 
@@ -109,28 +110,72 @@ _READ_CHUNK = 32
 
 
 def _pool_patch_fn(layout):
-    """Jitted device-side pool patch for the views-resident pool: scatter
-    ``rows`` (fresh page payloads — admissions, lazy materialisations)
-    at ``write_dst`` and duplicate ``copy_src -> copy_dst`` (COW splits),
-    without ever round-tripping the whole pool through the host.  All
-    index operands are fixed-width (:data:`_PATCH_CHUNK`), padded with
-    the scratch page index so one compilation serves every patch."""
+    """Jitted device-side pool patch by page id for the views-resident
+    pool: the pages ``fill_dst`` become the absent page (lazy
+    materialisations) and ``copy_src -> copy_dst`` duplicate (COW
+    splits).  Only page ids cross the host link.  All index operands are
+    fixed-width (:data:`_PATCH_CHUNK`), padded with the scratch page
+    index so one compilation serves every patch."""
+    import jax
+    import jax.numpy as jnp
+
+    empty_views = layout.decode_views(
+        jnp.asarray(np.asarray(layout.empty_page_row())[None])
+    )
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def patch(views, fill_dst, copy_src, copy_dst):
+        def apply(pool, init):
+            # a page materialised this tick is held by one table alone,
+            # so no copy sources it: the order of the two is free
+            pool = pool.at[:, fill_dst].set(init)
+            return pool.at[:, copy_dst].set(pool[:, copy_src])
+
+        return jax.tree_util.tree_map(apply, views, empty_views)
+
+    return patch
+
+
+def _pool_upload_fn(layout):
+    """Jitted write of carrier ``rows`` at pages ``dst`` of the
+    views-resident pool: the host mirror goes back up after a host sync,
+    a :data:`_PATCH_CHUNK` of pages at a time (padded with the scratch
+    page), never as one float32 carrier of the whole pool."""
     import jax
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def patch(views, write_dst, rows, copy_src, copy_dst):
-        rowviews = layout.decode_views(rows)
+    def upload(views, dst, rows):
+        return jax.tree_util.tree_map(
+            lambda pool, rv: pool.at[:, dst].set(rv),
+            views, layout.decode_views(rows),
+        )
 
-        def apply(pool, rv):
-            # writes first: a copy source may itself be a page written
-            # this very tick (same-tick admission then COW share)
-            pool = pool.at[:, write_dst].set(rv)
-            pool = pool.at[:, copy_dst].set(pool[:, copy_src])
-            return pool
+    return upload
 
-        return jax.tree_util.tree_map(apply, views, rowviews)
 
-    return patch
+def _admit_pages_fn(layout):
+    """Jitted write of an admission's fresh prompt pages into the
+    views-resident pool, straight from the prefill's cache on the
+    device: every ``(L, 1, cache_len, *tail)`` cache leaf is cut into
+    ``(L, n_pages, page_tokens, *tail)`` pages and logical page
+    ``src[j]`` lands at physical page ``dst[j]``.  No payload crosses
+    the host link.  ``src``/``dst`` are ``n_pages`` wide, padded with the
+    scratch page as ``dst``, so one compilation serves every admission."""
+    import jax
+
+    T = layout.page_tokens
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def admit_pages(views, caches, src, dst):
+        def put(pool, leaf):
+            pages = leaf.reshape(
+                (leaf.shape[0], -1, T) + tuple(leaf.shape[3:])
+            )
+            return pool.at[:, dst].set(pages[:, src].astype(pool.dtype))
+
+        return jax.tree_util.tree_map(put, views, caches)
+
+    return admit_pages
 
 
 def _pool_read_fn(layout):
@@ -433,6 +478,15 @@ class PagedServer(Server):
     Token parity with :class:`Server` — pressured or not — is the
     correctness bar (asserted in the smoke demo and tests).
 
+    Page payloads live in the device pool (``_dev_views``); the store's
+    ``mem`` host mirror is authoritative only between a host sync
+    (preemption, swap, resume, drain) and the next upload.  While the
+    device pool is live the host keeps bookkeeping alone: an admission
+    writes its fresh prompt pages from the prefill's cache on the device
+    (:func:`_admit_pages_fn`), lazy growth and COW splits patch pages by
+    id (:func:`_pool_patch_fn`), and the decode step updates the pool in
+    place.
+
     On the paged path each part of a tick's host work runs in a
     ``serve.*`` span (:func:`repro.obs.trace.span`: in the profiler's
     trace, beside the device's operations), and ``metrics`` counts what
@@ -440,10 +494,12 @@ class PagedServer(Server):
     recompute resumes), ``prefill_tokens``, ``prefill_cached_tokens``
     (prompt tokens on shared prefix pages), ``ticks`` and
     ``decode_rows`` (decode steps, and live rows summed over them),
-    ``d2h_bytes`` (flattened prompt caches and pool syncs to the host),
-    ``h2d_bytes`` (patch programs' operands and pool re-uploads),
-    ``host_syncs``, and the gauge ``pool_pages_peak`` (most pool pages
-    in use at a decode step).
+    ``pages_written_on_device`` (fresh prompt pages written from a
+    prefill's cache on the device), ``pages_filled_on_device`` (lazy
+    growth pages made absent on the device), ``d2h_bytes`` (pool syncs
+    to the host), ``h2d_bytes`` (the page ids of admission and patch
+    programs, and pool re-uploads), ``host_syncs``, and the gauge
+    ``pool_pages_peak`` (most pool pages in use at a decode step).
     """
 
     def __init__(self, model, ctx, params, batch_size: int, cache_len: int,
@@ -496,55 +552,46 @@ class PagedServer(Server):
         # live high-water mark of page-table width (monotonic; each
         # growth is one fused-step recompile)
         self._table_width = 1
-        # host-side page mutations queued for the device-resident pool:
-        # fresh payload rows (admissions, lazy materialisations) and COW
-        # src->dst splits, applied by the jitted patch program before the
-        # next decode step (or before any host sync)
+        # page mutations by id queued for the device-resident pool: lazy
+        # materialisations (made absent) and COW src->dst splits, applied
+        # by the jitted patch program before the next decode step (or
+        # before any host sync)
         self._patch = _pool_patch_fn(self.layout)
+        self._upload = _pool_upload_fn(self.layout)
+        self._admit_pages = _admit_pages_fn(self.layout)
         self._read = _pool_read_fn(self.layout)
-        self._pending_rows: Dict[int, np.ndarray] = {}
+        self._pending_fills: List[int] = []
         self._pending_copies: List[tuple] = []
         self.metrics = Registry()
         for name in ("admissions", "prefill_tokens", "prefill_cached_tokens",
-                     "ticks", "decode_rows", "d2h_bytes", "h2d_bytes",
+                     "ticks", "decode_rows", "pages_written_on_device",
+                     "pages_filled_on_device", "d2h_bytes", "h2d_bytes",
                      "host_syncs"):
             self.metrics.counter(name)
         self.metrics.gauge("pool_pages_peak")
 
+    def _has_pending(self) -> bool:
+        return bool(self._pending_fills or self._pending_copies)
+
     def _apply_pending(self) -> None:
-        """Flush queued page writes/copies into the device-resident pool.
-        Writes flush (in chunks) before any copy: a COW split may source
-        a page admitted this same tick."""
+        """Flush the queued page fills and copies into the device-resident
+        pool, by page id, :data:`_PATCH_CHUNK` ids a program."""
         with obs_trace.span("serve.patch"):
-            jnp = self.jnp
             P = self.store.state.n_pages  # scratch index pads the chunks
-            elems = self.layout.page_elems
-            rows = list(self._pending_rows.items())
-            copies = list(self._pending_copies)
-            self._pending_rows.clear()
-            self._pending_copies.clear()
-            pad_idx = np.full((_PATCH_CHUNK,), P, np.int32)
-            pad_rows = np.zeros((_PATCH_CHUNK, elems), np.float32)
+            fills, copies = self._pending_fills, self._pending_copies
+            self._pending_fills, self._pending_copies = [], []
+            self.metrics.counter("pages_filled_on_device").inc(len(fills))
             h2d = self.metrics.counter("h2d_bytes")
-
-            def send(wd, wr, cs, cd):
-                ops = (wd, wr, cs, cd)
-                self._dev_views = self._patch(
-                    self._dev_views, *(jnp.asarray(x) for x in ops))
-                h2d.inc(sum(x.nbytes for x in ops))
-
-            while rows:
-                chunk, rows = rows[:_PATCH_CHUNK], rows[_PATCH_CHUNK:]
-                wd, wr = pad_idx.copy(), pad_rows.copy()
-                for j, (pg, row) in enumerate(chunk):
-                    wd[j], wr[j] = pg, row
-                send(wd, wr, pad_idx, pad_idx)
-            while copies:
+            while fills or copies:
+                ids = np.full((3, _PATCH_CHUNK), P, np.int32)
+                chunk, fills = fills[:_PATCH_CHUNK], fills[_PATCH_CHUNK:]
+                ids[0, : len(chunk)] = chunk
                 chunk, copies = copies[:_PATCH_CHUNK], copies[_PATCH_CHUNK:]
-                cs, cd = pad_idx.copy(), pad_idx.copy()
-                for j, (src, dst) in enumerate(chunk):
-                    cs[j], cd[j] = src, dst
-                send(pad_idx, pad_rows, cs, cd)
+                if chunk:
+                    ids[1:, : len(chunk)] = np.asarray(chunk, np.int32).T
+                self._dev_views = self._patch(
+                    self._dev_views, *(self.jnp.asarray(x) for x in ids))
+                h2d.inc(ids.nbytes)
 
     def _sync_host(self) -> None:
         """Land the device-resident pool back in the host mirror before
@@ -556,7 +603,7 @@ class PagedServer(Server):
         if self._dev_views is None:
             return
         with obs_trace.span("serve.sync_host"):
-            if self._pending_rows or self._pending_copies:
+            if self._has_pending():
                 self._apply_pending()
             P = self.store.state.n_pages
             d2h = self.metrics.counter("d2h_bytes")
@@ -691,40 +738,49 @@ class PagedServer(Server):
         self.last_token[slot, 0] = int(last_token)
 
     def _prefill_pages(self, req: Request):
+        """Prefill ``req``'s prompt; returns its first token and the
+        prefill's cache, left on the device."""
         with obs_trace.span("serve.prefill"):
             toks = self.jnp.asarray(req.prompt, self.jnp.int32)[None]
             logits, caches_one = self._prefill_one(
                 self.params, {"inputs": toks})
             tok = int(np.argmax(np.asarray(logits)[0]))
-        with obs_trace.span("serve.flatten"):
-            pages = np.asarray(self.layout.flatten(caches_one))
-        self.metrics.counter("d2h_bytes").inc(pages.nbytes)
-        return tok, pages, caches_one
+        return tok, caches_one
 
     def _admit_prompt(self, req: Request) -> int:
         """Prefill ``req``'s prompt into the pool (its leading full pages
         shared with resident prefixes, the tail left to lazy growth) and
-        commit its page table; returns the first token.  With the pool
-        on the device, only the fresh prompt pages queue, as patches,
-        instead of the whole pool round-tripping through the host."""
+        commit its page table; returns the first token.  The fresh prompt
+        pages go from the prefill's cache into the device pool on the
+        device (``serve.flatten``: the cache cut into pages there); the
+        host does the bookkeeping alone (``serve.pool_write``)."""
         with obs_trace.span("serve.admit"):
-            tok, pages, _ = self._prefill_pages(req)
+            tok, caches = self._prefill_pages(req)
+            self._ensure_dev_views()
             with obs_trace.span("serve.pool_write"):
-                plan = self.store.plan_admit(req.prompt, lazy=True)
-                self.store.write_pages(plan, pages)
-                self.store.commit(req.rid, plan)
-                if self._dev_views is not None:
-                    for page_id, is_fresh in zip(plan.table, plan.fresh):
-                        if is_fresh:
-                            self._pending_rows[page_id] = self.store.mem[
-                                page_id
-                            ].copy()
+                plan = self.store.admit_tables(req.rid, req.prompt)
+            with obs_trace.span("serve.flatten"):
+                P = self.store.state.n_pages
+                ids = np.zeros((2, self.layout.n_pages), np.int32)
+                ids[1] = P  # padding lands in the scratch page
+                fresh = [(lp, pp) for lp, (pp, f) in enumerate(
+                    zip(plan.table, plan.fresh)) if f]
+                if fresh:
+                    ids[:, : len(fresh)] = np.asarray(fresh, np.int32).T
+                    self._dev_views = self._admit_pages(
+                        self._dev_views, caches,
+                        *(self.jnp.asarray(x) for x in ids))
+                    self.metrics.counter("h2d_bytes").inc(ids.nbytes)
+        self._count_admission(req, plan, len(fresh))
+        return tok
+
+    def _count_admission(self, req: Request, plan, written: int) -> None:
         m = self.metrics
         m.counter("admissions").inc()
         m.counter("prefill_tokens").inc(len(req.prompt))
         m.counter("prefill_cached_tokens").inc(
             len(plan.shared) * self.layout.page_tokens)
-        return tok
+        m.counter("pages_written_on_device").inc(written)
 
     def _resume(self, rid: int, slot: int) -> bool:
         st = self._preempted[rid]
@@ -789,7 +845,9 @@ class PagedServer(Server):
             if self.store.n_free < self.layout.n_pages:
                 return
             req = self.queue.pop(0)
-            tok, pages, _ = self._prefill_pages(req)
+            tok, caches = self._prefill_pages(req)
+            pages = np.asarray(self.layout.flatten(caches))
+            self.metrics.counter("d2h_bytes").inc(pages.nbytes)
             self.store.admit(req.rid, req.prompt, pages)
             # the decode row is read back THROUGH the page table, so the
             # pool (not the prefill output) is the source of truth
@@ -829,19 +887,13 @@ class PagedServer(Server):
                         continue
                 pos = int(self.positions[i])
                 if need and self._dev_views is not None:
-                    # materialisation / COW split mutates page payloads: mirror
-                    # the host-side bookkeeping write as a device patch rather
-                    # than syncing the whole pool down and back up
-                    before = self.store.tables[req.rid][
-                        pos // self.layout.page_tokens
-                    ]
-                    dst = self.store.prepare_write(req.rid, pos)
-                    if before == UNMATERIALIZED:
-                        self._pending_rows[dst] = np.asarray(
-                            self.layout.empty_page_row()
-                        )
-                    elif dst != before:  # COW split: clone the shared payload
-                        self._pending_copies.append((int(before), int(dst)))
+                    # materialisation / COW split: bookkeeping on the host,
+                    # the page's payload patched on the device by id
+                    dst, origin = self.store.claim_write(req.rid, pos)
+                    if origin == UNMATERIALIZED:
+                        self._pending_fills.append(int(dst))
+                    elif dst != origin:  # COW split: clone the shared page
+                        self._pending_copies.append((int(origin), int(dst)))
                 else:
                     self.store.prepare_write(req.rid, pos)
         live = [i for i, r in enumerate(self.active) if r is not None]
@@ -888,31 +940,44 @@ class PagedServer(Server):
                 },
             )
 
+    def _ensure_dev_views(self) -> None:
+        """(Re-)upload the host mirror when it is authoritative: absent
+        pages on the device, then the live ones in page chunks — the
+        pool never crosses as one float32 carrier."""
+        if self._dev_views is not None:
+            return
+        with obs_trace.span("serve.upload"):
+            jnp = self.jnp
+            P = self.store.state.n_pages
+            row = self.layout.empty_page_row()[None]
+            empty = self.layout.decode_views(jnp.asarray(row))
+            h2d = self.metrics.counter("h2d_bytes")
+            h2d.inc(row.nbytes)
+            self._dev_views = self.jax.tree_util.tree_map(
+                lambda e: jnp.broadcast_to(
+                    e, e.shape[:1] + (P + 1,) + e.shape[2:]
+                ),
+                empty,
+            )
+            live = self._live_pages()
+            for lo in range(0, len(live), _PATCH_CHUNK):
+                dst = np.full((_PATCH_CHUNK,), P, np.int32)
+                chunk = live[lo : lo + _PATCH_CHUNK]
+                dst[: len(chunk)] = chunk
+                rows = np.zeros((_PATCH_CHUNK, row.shape[1]), np.float32)
+                rows[: len(chunk)] = self.store.mem[chunk]
+                self._dev_views = self._upload(
+                    self._dev_views, jnp.asarray(dst), jnp.asarray(rows))
+                h2d.inc(dst.nbytes + rows.nbytes)
+
     def _decode_via_tables(self, tables: np.ndarray) -> np.ndarray:
         """Upload the pool when host-resident, flush queued page patches,
         run the fused paged decode; returns host logits.  The device-pool
         representation is this method's private affair — the TP variant
         swaps in a head-sharded stacked carrier and a ``shard_map``-ped
         step without touching the scheduler loop above."""
-        if self._dev_views is None:  # (re-)upload the mutated host mirror
-            # empty pages on the device, then the live ones as patches:
-            # the pool crosses in page chunks, never as one float32 carrier
-            with obs_trace.span("serve.upload"):
-                P = self.store.state.n_pages
-                row = self.layout.empty_page_row()[None]
-                empty = self.layout.decode_views(self.jnp.asarray(row))
-                self.metrics.counter("h2d_bytes").inc(row.nbytes)
-                self._dev_views = self.jax.tree_util.tree_map(
-                    lambda e: self.jnp.broadcast_to(
-                        e, e.shape[:1] + (P + 1,) + e.shape[2:]
-                    ),
-                    empty,
-                )
-                for page in self._live_pages():
-                    self._pending_rows.setdefault(
-                        int(page), self.store.mem[page].copy()
-                    )
-        if self._pending_rows or self._pending_copies:
+        self._ensure_dev_views()
+        if self._has_pending():
             self._apply_pending()
         with obs_trace.span("serve.decode"):
             logits, self._dev_views = self._decode_paged(
@@ -1030,9 +1095,12 @@ class TPPagedServer(PagedServer):
     mirror all stay in the FULL layout (pages are sharded by *bytes*, not
     by id — every rank holds the same table).  Only the device residency
     differs: ``_dev_views`` becomes the stacked ``(tp, P+1, shard_elems)``
-    carrier, patches pre-shard queued host rows through ``shard_cols``,
-    and ``_sync_host`` reassembles the shards bit-exactly.  Token streams
-    are identical to ``tp=1`` (asserted in tests and the bench section).
+    carrier, and ``_sync_host`` reassembles the shards bit-exactly.
+    Admission takes the host route: the prefill's cache is flattened to
+    the host, written into the mirror, and its fresh pages go up as
+    payload rows pre-sharded through ``shard_cols`` (with lazy-growth
+    pages, as absent rows).  Token streams are identical to ``tp=1``
+    (asserted in tests and the bench section).
     """
 
     def __init__(self, model, ctx, params, batch_size: int, cache_len: int,
@@ -1065,15 +1133,44 @@ class TPPagedServer(PagedServer):
             costs=sched_cost_table,
         )
         self._patch_tp = _tp_pool_patch_fn()
+        # fresh payload rows (admissions) queued for the stacked carrier
+        self._pending_rows: Dict[int, np.ndarray] = {}
 
     # -- device-pool representation overrides --------------------------- #
+    def _admit_prompt(self, req: Request) -> int:
+        """The host route: flatten the prefill's cache to the host, write
+        its fresh pages into the mirror and queue them as payload rows."""
+        with obs_trace.span("serve.admit"):
+            tok, caches = self._prefill_pages(req)
+            with obs_trace.span("serve.flatten"):
+                pages = np.asarray(self.layout.flatten(caches))
+            self.metrics.counter("d2h_bytes").inc(pages.nbytes)
+            with obs_trace.span("serve.pool_write"):
+                plan = self.store.plan_admit(req.prompt, lazy=True)
+                self.store.write_pages(plan, pages)
+                self.store.commit(req.rid, plan)
+                if self._dev_views is not None:
+                    for page_id, is_fresh in zip(plan.table, plan.fresh):
+                        if is_fresh:
+                            self._pending_rows[page_id] = self.store.mem[
+                                page_id
+                            ].copy()
+        self._count_admission(req, plan, 0)
+        return tok
+
+    def _has_pending(self) -> bool:
+        return bool(self._pending_rows) or super()._has_pending()
+
     def _apply_pending(self) -> None:
         jnp = self.jnp
         Pn = self.store.state.n_pages  # scratch index pads the chunks
         elems = self.shard_layout.page_elems
+        empty = self.layout.empty_page_row()
         rows = list(self._pending_rows.items())
+        rows += [(pg, empty) for pg in self._pending_fills]
         copies = list(self._pending_copies)
         self._pending_rows.clear()
+        self._pending_fills.clear()
         self._pending_copies.clear()
         pad_idx = np.full((_PATCH_CHUNK,), Pn, np.int32)
         pad_rows = np.zeros((self.tp, _PATCH_CHUNK, elems), np.float32)
@@ -1100,7 +1197,7 @@ class TPPagedServer(PagedServer):
     def _sync_host(self) -> None:
         if self._dev_views is None:
             return
-        if self._pending_rows or self._pending_copies:
+        if self._has_pending():
             self._apply_pending()
         Pn = self.store.state.n_pages
         stacked = np.asarray(self._dev_views)  # (tp, P+1, shard_elems)
@@ -1120,7 +1217,7 @@ class TPPagedServer(PagedServer):
             self._dev_views = self.jax.device_put(
                 jnp.asarray(stacked), self._tp_sharding
             )
-        if self._pending_rows or self._pending_copies:
+        if self._has_pending():
             self._apply_pending()
         logits, self._dev_views = self._decode_tp(
             self._stacked_params,

@@ -204,6 +204,7 @@ def apply_attention(
     cache_len: int = 0,
     xkv: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     tp=None,
 ) -> Tuple[jax.Array, Optional[Params]]:
     """Self- or cross-attention sub-block (pre-norm, residual added by caller).
@@ -216,12 +217,15 @@ def apply_attention(
     (B, S_enc, D); cache (mode != train) stores the projected enc KV.
 
     Paged decode (``page_table`` given, decode mode only): ``cache`` holds
-    the layer's slice of the global KV *page pool* — ``k``/``v`` shaped
-    ``(P, page_tokens, KH, Dh)`` and ``pos`` ``(P, page_tokens)`` — and
-    ``page_table`` is ``(B, NP)`` physical ids per request.  The new
+    the global KV *page pool* of every layer of the stack — ``k``/``v``
+    shaped ``(L, P, page_tokens, KH, Dh)`` and ``pos``
+    ``(L, P, page_tokens)`` — ``layer`` is this layer's index into it,
+    and ``page_table`` is ``(B, NP)`` physical ids per request.  The new
     token's K/V scatter straight into the request's (COW-resolved,
-    materialised) page and attention runs through the table via
-    ``kernels.paged_attention`` — no dense per-request rows anywhere.
+    materialised) page at ``[layer, page, slot]``, and attention runs
+    through the table via ``kernels.paged_attention``, reading that layer
+    of the pool — no dense per-request rows, and no copy of a layer's
+    pool, anywhere.
 
     Tensor-parallel (``tp`` a :class:`~repro.parallel.tp.TPGroup`): the
     caller passes head-sharded ``wq/wk/wv/wo`` — every per-head
@@ -316,18 +320,18 @@ def apply_attention(
         if window is not None:
             raise ValueError("paged decode does not support local windows")
         kp, vp, pp = cache["k"], cache["v"], cache["pos"]  # page pools
-        T = kp.shape[1]  # page_tokens
+        T = kp.shape[2]  # page_tokens
         pos = positions[:, 0]  # (B,)
         # the write page: COW-resolved and materialised by the host before
         # the step, so live rows never collide (dead rows all target the
         # scratch page with identical values — deterministic scatter)
         phys = page_table[jnp.arange(B), pos // T]
         slot = pos % T
-        kp = kp.at[phys, slot].set(k[:, 0])
-        vp = vp.at[phys, slot].set(v[:, 0])
-        pp = pp.at[phys, slot].set(pos)
+        kp = kp.at[layer, phys, slot].set(k[:, 0])
+        vp = vp.at[layer, phys, slot].set(v[:, 0])
+        pp = pp.at[layer, phys, slot].set(pos)
         out = ops.paged_attention(
-            q[:, 0], kp, vp, page_table, pos + 1, scale=scale,
+            q[:, 0], kp, vp, page_table, pos + 1, layer, scale=scale,
             impl=kmode.decode_attn_impl(ctx.attn_impl),
         )[:, None]
         new_cache = {"k": kp, "v": vp, "pos": pp}

@@ -76,6 +76,7 @@ def block_apply(
     positions: jax.Array,
     xkv: Optional[jax.Array],
     page_table: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     tp=None,
 ) -> Tuple[jax.Array, Optional[Params]]:
     get = lambda k: None if cache is None else cache.get(k)
@@ -89,7 +90,7 @@ def block_apply(
         a, ac = L.apply_attention(
             p["attn"], cfg, ctx, x, positions=positions, causal=causal,
             window=window, mode=mode, cache=get("attn"), cache_len=cache_len,
-            page_table=page_table, tp=tp,
+            page_table=page_table, layer=layer, tp=tp,
         )
         x = x + checkpoint_name(a, "attn_out")
         if ac is not None:
@@ -218,7 +219,7 @@ def stack_apply(
     for si, (seg, sp) in enumerate(zip(segments, seg_params)):
         sc = caches[si] if caches is not None else None
 
-        def unit_body(xc, lp, lc):
+        def unit_body(xc, lp, lc, layer=None):
             ncs = {}
             for i, kind in enumerate(seg.unit):
                 key = f"b{i}_{kind}"
@@ -226,7 +227,7 @@ def stack_apply(
                     kind, lp[key], cfg, ctx, xc, mode=mode,
                     cache=None if lc is None else lc[key],
                     cache_len=cache_len, positions=positions, xkv=xkv,
-                    page_table=page_table, tp=tp,
+                    page_table=page_table, layer=layer, tp=tp,
                 )
                 if nc is not None:
                     ncs[key] = nc
@@ -243,6 +244,20 @@ def stack_apply(
                 return unit_body(xc, lp, None)
 
             x, ncs = lax.scan(body_p, x, sp)
+            new_caches.append(ncs)
+        elif mode == "decode" and page_table is not None:
+            # paged decode: the (L, P, T, ...) page pools ride the scan's
+            # carry, and each layer scatters its token at [layer, page,
+            # slot] and reads its layer in the kernel.  Pools as xs/ys
+            # would cut every layer's pool out and write it to a fresh
+            # stack; carried, the donated pool is updated in place.
+            def body_pd(carry, lp_l):
+                (xc, pools), (lp, layer) = carry, lp_l
+                return unit_body(xc, lp, pools, layer), None
+
+            (x, ncs), _ = lax.scan(
+                body_pd, (x, sc), (sp, jnp.arange(seg.count, dtype=jnp.int32))
+            )
             new_caches.append(ncs)
         elif mode == "decode":
             def body_d(xc, lp_lc):

@@ -667,6 +667,17 @@ class PagedKVStore:
     def commit(self, rid: int, plan: AdmitPlan) -> None:
         self.tables[rid] = plan.table
 
+    def admit_tables(
+        self, rid: int, prompt: Sequence[int], lazy: bool = True
+    ) -> AdmitPlan:
+        """plan + commit with no payload write: the bookkeeping of an
+        admission whose fresh pages the caller writes elsewhere (the
+        colocated server writes them into its device-resident pool from
+        the prefill's cache, on the device).  ``mem`` is not touched."""
+        plan = self.plan_admit(prompt, lazy=lazy)
+        self.commit(rid, plan)
+        return plan
+
     def write_pages(self, plan: AdmitPlan, pages: Any) -> None:
         """Host write of the fresh pages (the colocated path; the
         disaggregated path lands them one-sided into the segment)."""
@@ -735,35 +746,44 @@ class PagedKVStore:
 
     def prepare_write(self, rid: int, position: int) -> int:
         """Make the page holding ``position`` writable for ``rid`` and
-        return its physical id: a lazy slot materialises (alloc), a
-        shared page copy-on-write splits, and the written page leaves the
-        prefix index (its chain no longer matches).  This is the
-        bookkeeping half of a decode-step write; the payload lands either
-        host-side (:meth:`write_token_page`) or on-device (the paged
-        decode step scattering straight into the pool)."""
+        return its physical id (:meth:`claim_write`), and give a fresh
+        page its payload in ``mem``: a materialised slot the absent page,
+        so the bytes of whoever held it before never resurface; a
+        copy-on-write split a bit-exact copy of the shared original.  The
+        token's own payload lands either host-side
+        (:meth:`write_token_page`) or on-device (the paged decode step
+        scattering straight into the pool)."""
+        dst, origin = self.claim_write(rid, position)
+        if origin == UNMATERIALIZED:
+            self.mem[dst] = self.layout.empty_page_row()
+        elif origin != dst:
+            self.mem[dst] = self.mem[origin]
+        return dst
+
+    def claim_write(self, rid: int, position: int) -> Tuple[int, int]:
+        """The bookkeeping half of :meth:`prepare_write`, with ``mem``
+        untouched: a lazy slot materialises (alloc), a shared page
+        copy-on-write splits, and the written page leaves the prefix
+        index (its chain no longer matches).  Returns ``(dst, origin)``:
+        ``origin`` is :data:`UNMATERIALIZED` when ``dst`` was just
+        allocated (its payload must become the absent page), the shared
+        page when ``dst`` split from it (its payload must be copied), and
+        ``dst`` itself when the page was already writable."""
         table = list(self.tables[rid])
         p = position // self.layout.page_tokens
-        page_id = table[p]
-        if page_id == UNMATERIALIZED:
+        origin = table[p]
+        if origin == UNMATERIALIZED:
             self.state, (dst,) = alloc(self.state, 1)
+        else:
+            self.state, dst, _ = writable(self.state, origin)
+        if dst != origin:
             table[p] = dst
             self.tables[rid] = tuple(table)
-            # a materialising page starts absent: synthesise its init row
-            # so the bytes of whoever held it before never resurface
-            self.mem[dst] = self.layout.empty_page_row()
-        else:
-            self.state, dst, copied = writable(self.state, page_id)
-            if copied:
-                table[p] = dst
-                self.tables[rid] = tuple(table)
-                # COW payload copy: the fresh page starts as a bit-exact
-                # copy of the shared original
-                self.mem[dst] = self.mem[page_id]
         # a mutated page no longer matches its prompt chain: drop the key
         key = self._page_key.pop(dst, None)
         if key is not None and self._prefix.get(key) == dst:
             del self._prefix[key]
-        return dst
+        return dst, origin
 
     def write_token_page(self, rid: int, position: int, page_row: Any) -> int:
         """Install the page holding ``position`` after a decode step wrote

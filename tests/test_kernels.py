@@ -93,6 +93,36 @@ def test_paged_attention_vs_oracle(case):
     )
 
 
+@pytest.mark.parametrize("impl,ppb,bb", [
+    ("ref", None, None), ("pallas", 1, 1), ("pallas", 2, 2),
+    ("pallas", 4, 3), ("pallas", None, None),
+])
+def test_paged_attention_layer_of_stacked_pool(impl, ppb, bb):
+    """A 5-D pool read at ``layer=l`` equals the 4-D call on ``pool[l]``,
+    bit for bit, for every layer and blocking."""
+    B, Hq, Hkv, D, T, NP, L = 3, 4, 2, 16, 4, 3, 3
+    P = B * NP + 1
+    q = _rand((B, Hq, D))
+    kp = _rand((L, P, T, Hkv, D))
+    vp = _rand((L, P, T, Hkv, D))
+    table = jnp.asarray(
+        RNG.permutation(P)[: B * NP].reshape(B, NP), jnp.int32
+    )
+    lengths = jnp.asarray([NP * T, 5, 1], jnp.int32)
+    kw = {} if impl == "ref" else {"pages_per_block": ppb, "block_b": bb}
+    for layer in range(L):
+        got = ops.paged_attention(
+            q, kp, vp, table, lengths, jnp.int32(layer), impl=impl, **kw)
+        want = ops.paged_attention(
+            q, kp[layer], vp[layer], table, lengths, impl=impl, **kw)
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    with pytest.raises(ValueError):
+        ops.paged_attention(q, kp, vp, table, lengths, impl=impl, **kw)
+    with pytest.raises(ValueError):
+        ops.paged_attention(q, kp[0], vp[0], table, lengths,
+                            jnp.int32(0), impl=impl, **kw)
+
+
 def test_paged_attention_matches_dense_attention():
     """Contiguous identity table + full length == dense decode attention."""
     B, Hq, Hkv, D, T, NP = 2, 4, 2, 16, 4, 4

@@ -77,6 +77,70 @@ def test_paged_attention_qwen3_4b_decode(one_chip, page_tokens):
     assert _kernels(compiled)
 
 
+def test_paged_attention_layer_of_stacked_pool_qwen3_4b(one_chip):
+    # the pools of all 36 layers, read at a layer index inside the DMAs
+    B, Hq, Hkv, D, L, pages, T, width = 16, 32, 8, 128, 36, 1025, 16, 64
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kv = s((L, pages, T, Hkv, D), jnp.bfloat16)
+    compiled = pa.paged_attention.lower(
+        s((B, Hq, D), jnp.bfloat16), kv, kv,
+        s((B, width), jnp.int32), s((B,), jnp.int32), s((), jnp.int32),
+    ).compile()
+    assert _kernels(compiled)
+
+
+def test_paged_decode_step_updates_the_pool_in_place(one_chip):
+    """Two layers of qwen3-4b at published widths, a 1024-page pool plus
+    the scratch page, the pool donated: the step's HLO copies no pool,
+    whole or one layer of it, and cuts no layer's pool out."""
+    import dataclasses
+    import re
+
+    from repro.configs.registry import ARCHS
+    from repro.launch.serve import _paged_decode_views_fn
+    from repro.models.build import build_model
+    from repro.parallel.ctx import RunCtx
+    from repro.serving.pool import PagedLayout
+
+    cfg = dataclasses.replace(ARCHS["qwen3-4b"], n_layers=2)
+    model = build_model(cfg)
+    ctx = RunCtx(mesh=None, remat="none")
+    B, P, T, cache_len = 16, 1024, 16, 1024
+    layout = PagedLayout.from_struct(
+        model.kv_block_struct(ctx, prompt_len=4, cache_len=cache_len),
+        cache_len=cache_len, page_tokens=T,
+    )
+    views = jax.eval_shape(layout.decode_views, jax.ShapeDtypeStruct(
+        (P + 1, layout.page_elems), jnp.float32))
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params, _ = model.abstract_init(ctx)
+    compiled = _paged_decode_views_fn(model, ctx, layout).lower(
+        jax.tree.map(s, params), s(jax.ShapeDtypeStruct((B, 1), jnp.int32)),
+        s(jax.ShapeDtypeStruct((B,), jnp.int32)), jax.tree.map(s, views),
+        s(jax.ShapeDtypeStruct((B, layout.n_pages), jnp.int32)),
+    ).compile()
+    text = compiled.as_text()
+    assert _kernels(compiled)
+    # (result shape, opcode) of every instruction
+    ops = re.findall(r" = (\S+) ([\w-]+)\(", text)
+    assert any(op == "copy" for _, op in ops)  # the parse finds copies
+    pool = re.compile(r"\[(2,)?%d,%d[,\]]" % (P + 1, T))
+    copies = [sh for sh, op in ops if op == "copy" and pool.search(sh)]
+    assert not copies, copies
+    # one layer of a pool: a slice cut out of the stack, never made
+    layer = re.compile(r"\[%d,%d[,\]]" % (P + 1, T))
+    assert not [(sh, op) for sh, op in ops if layer.search(sh)]
+    # the donated pool is updated in place: no temporary of pool size
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        P + 1) * T * 8 * 128 * 2
+
+
 def _compile_on_nodes(mesh, body, *shapes):
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("node"),
                            out_specs=P("node"), check_vma=False))
